@@ -5,11 +5,10 @@ The hot-path rows time the *tuned* entry points (``pack_rows``,
 ``segment_reduce_rows``) — the lowering the SF backends actually execute —
 in compiled mode where the platform supports it (TPU Mosaic) and interpret
 mode elsewhere; every timing records which mode it ran in and, for tuned
-rows, which candidate lowering the autotuner picked.  The historical
-one-row-per-grid-step DMA kernel is still timed (few iterations — in
-interpret mode its per-step overhead is exactly the gap this layer closed)
-so the trajectory keeps both curves.  Results land in ``BENCH_kernels.json``
-with the environment stamp from :mod:`benchmarks.artifacts`; the CI perf
+rows, which candidate lowering the autotuner picked.  The untuned pack
+kernel at its smallest block (one output tile per grid step) is timed too
+(few iterations in interpret mode), so the trajectory keeps both curves.
+Results land in ``BENCH_kernels.json`` with the environment stamp from :mod:`benchmarks.artifacts`; the CI perf
 guard (``benchmarks/perf_guard.py``) compares fresh ``pack_rows`` timings
 against the committed artifact."""
 
@@ -65,11 +64,11 @@ def run(json_path=DEFAULT_JSON):
     us = _t(lambda d, i: K.pack_rows(d, i, key=key), data, idx)
     add("pack_kernel_128x128", us, "tuned", impl=_impl("pack", key))
     add("pack_ref_128x128", _t(lambda d, i: R.pack_ref(d, i), data, idx))
-    # the historical one-row-per-grid-step DMA kernel (few iters: in
-    # interpret mode each of the 128 grid steps costs ~0.4ms)
+    # the untuned kernel at its smallest block, one output tile per grid
+    # step (few iters in interpret mode)
     add("pack_rowdma_128x128", _t(K.sf_pack, data, idx,
                                   iters=1 if interp else 10),
-        "one-row-per-step")
+        "one-tile-per-step")
     # §5.2 ¶3 parametric strided pack: same 128 rows, no index array at all
     add("pack_strided_kernel_4x4x8",
         _t(lambda d: K.sf_pack_strided(d, start=2, dims=(4, 4, 8),

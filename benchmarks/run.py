@@ -47,6 +47,8 @@ def main() -> None:
                          "pingpong,async,cg,meshdist,spmm,kernels,halo,"
                          "serving,ddp,assembly")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_assembly, bench_async, bench_cg, bench_ddp,
                             bench_halo, bench_kernels, bench_meshdist,
                             bench_pingpong, bench_serving, bench_spmm)

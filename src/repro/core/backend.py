@@ -227,12 +227,14 @@ class PallasBackend:
     def _pack(self, data: jnp.ndarray, idx: np.ndarray,
               strided: Optional[pat.Strided3D] = None) -> jnp.ndarray:
         """rows ``data[idx]`` via the pack kernel (strided variant when the
-        enumeration is parametric).  Both kernels block over the full
-        ``(*unit)`` row shape, so payloads pass through unreshaped."""
-        if strided is None:
+        enumeration is parametric and, compiled, its panels are whole
+        tiles).  Both kernels take the full ``(*unit)`` row shape, so
+        payloads pass through unreshaped."""
+        data = jnp.asarray(data)
+        if strided is None or not (self.interpret or kops.strided_ok(
+                strided.dims, data.dtype)):
             return kops.pack_rows(data, idx, interpret=self.interpret,
                                   key=self._tune_key)
-        data = jnp.asarray(data)
         unit = data.shape[1:]
         usize = int(np.prod(unit)) if unit else 1
         M = int(np.size(idx))
@@ -408,9 +410,28 @@ class _DeferredComm:
         return out
 
 
+def _stack_maps(offsets, pad: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Index maps between global rows (rank-concatenated, ``offsets``) and
+    the padded ``(R, pad)`` shard stack: the (R, pad) gather (pad slots
+    read row ``offsets[-1]``, an appended zero row) and the flat stack
+    position of every global row."""
+    offsets = np.asarray(offsets, dtype=np.int64)
+    R, total = offsets.size - 1, int(offsets[-1])
+    gather = np.full((R, pad), total, dtype=np.int32)
+    flat = np.empty(total, dtype=np.int32)
+    for r in range(R):
+        n = int(offsets[r + 1] - offsets[r])
+        gather[r, :n] = offsets[r] + np.arange(n)
+        flat[offsets[r]: offsets[r + 1]] = r * pad + np.arange(n)
+    return gather, flat
+
+
 class ShardmapBackend:
-    """Explicit rank decomposition: pad per-rank shards, run the DistSF
-    shard_map lowering over a device mesh, trim the result."""
+    """Explicit rank decomposition: gather the global array into padded
+    per-rank shards, run the DistSF shard_map lowering over a device mesh,
+    gather the result back.  Every step is a jnp gather on device, so the
+    backend also runs under ``jax.jit`` (e.g. inside a CG step); the
+    stacked shards are placed one rank per mesh device."""
 
     name = "shardmap"
 
@@ -430,6 +451,7 @@ class ShardmapBackend:
                     f"({sf.nranks}), have {len(devs)}; pass a mesh or pick "
                     f"another backend")
             mesh = jax.make_mesh((sf.nranks,), (axis_name,),
+                                 (jax.sharding.AxisType.Auto,),
                                  devices=devs[: sf.nranks])
         if int(np.prod(mesh.devices.shape)) != sf.nranks:
             raise ValueError(
@@ -438,6 +460,11 @@ class ShardmapBackend:
         self.mesh = mesh
         self._fns: Dict[Tuple[str, str], Callable] = {}
         self._globalops: Optional[GlobalBackend] = None
+        p = self.dist.plan
+        self._shard = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec(axis_name))
+        self._root_maps = _stack_maps(sf.root_offsets(), p.root_pad)
+        self._leaf_maps = _stack_maps(sf.leaf_offsets(), p.leaf_pad)
 
     @property
     def unit(self):
@@ -453,18 +480,22 @@ class ShardmapBackend:
             self._fns[key] = maker(self.mesh, op=opname)
         return self._fns[key]
 
-    def _split(self, data, offsets) -> list:
-        data = np.asarray(data)
-        return [data[int(offsets[r]): int(offsets[r + 1])]
-                for r in range(self.sf.nranks)]
+    def _stack(self, data, maps):
+        """Global rows -> (R, pad, *unit) shards (pad rows read zeros),
+        one rank per mesh device."""
+        data = jnp.asarray(data)
+        gather, _ = maps
+        zero = jnp.zeros((1,) + data.shape[1:], data.dtype)
+        return jax.device_put(
+            jnp.take(jnp.concatenate([data, zero]), gather, axis=0),
+            self._shard)
 
-    def _root_stack(self, rootdata):
-        return jnp.asarray(self.dist.pad_root_stack(
-            self._split(rootdata, self.sf.root_offsets())))
-
-    def _leaf_stack(self, leafdata):
-        return jnp.asarray(self.dist.pad_leaf_stack(
-            self._split(leafdata, self.sf.leaf_offsets())))
+    @staticmethod
+    def _unstack(stacked, maps):
+        """(R, pad, *unit) shards -> global rows."""
+        _, flat = maps
+        rows = stacked.reshape((-1,) + stacked.shape[2:])
+        return jnp.take(rows, flat, axis=0)
 
     # ------------------------------------------------------------ ops
     def bcast_begin(self, rootdata, op="replace") -> _DeferredComm:
@@ -475,11 +506,9 @@ class ShardmapBackend:
 
     def bcast(self, rootdata, leafdata, op="replace"):
         out = self._fn("bcast", get_op(op).name)(
-            self._root_stack(rootdata), self._leaf_stack(leafdata))
-        return jnp.asarray(np.concatenate(self.dist.unpad_leaf_stack(out))
-                           if self.sf.nleafspace_total else
-                           np.zeros((0,) + np.asarray(leafdata).shape[1:],
-                                    np.asarray(leafdata).dtype))
+            self._stack(rootdata, self._root_maps),
+            self._stack(leafdata, self._leaf_maps))
+        return self._unstack(out, self._leaf_maps)
 
     def reduce_begin(self, leafdata, op="sum") -> _DeferredComm:
         return _DeferredComm("reduce", self, leafdata, op)
@@ -489,20 +518,16 @@ class ShardmapBackend:
 
     def reduce(self, leafdata, rootdata, op="sum"):
         out = self._fn("reduce", get_op(op).name)(
-            self._leaf_stack(leafdata), self._root_stack(rootdata))
-        return jnp.asarray(np.concatenate(self.dist.unpad_root_stack(out))
-                           if self.sf.nroots_total else
-                           np.zeros((0,) + np.asarray(rootdata).shape[1:],
-                                    np.asarray(rootdata).dtype))
+            self._stack(leafdata, self._leaf_maps),
+            self._stack(rootdata, self._root_maps))
+        return self._unstack(out, self._root_maps)
 
     def fetch_and_op(self, rootdata, leafdata, op="sum"):
         ro, lu = self._fn("fetch", get_op(op).name)(
-            self._root_stack(rootdata), self._leaf_stack(leafdata))
-        root_out = jnp.asarray(np.concatenate(
-            self.dist.unpad_root_stack(ro)))
-        leafupd = jnp.asarray(np.concatenate(
-            self.dist.unpad_leaf_stack(lu)))
-        return root_out, leafupd
+            self._stack(rootdata, self._root_maps),
+            self._stack(leafdata, self._leaf_maps))
+        return (self._unstack(ro, self._root_maps),
+                self._unstack(lu, self._leaf_maps))
 
     # gather/scatter reorganize into the multi-root layout, a host-derived
     # index transform shared with the global backend.

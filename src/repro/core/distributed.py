@@ -41,11 +41,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .graph import StarForest
 from .mpiops import Op, get_op
 from .plan import PaddedPlan, build_padded_plan
@@ -57,14 +52,10 @@ __all__ = ["DistSF", "DistPending", "pad_ragged", "unpad_ragged"]
 
 
 def _smap(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off (Pallas calls inside the
-    mapped function have no replication rule)."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - newer API dropped check_rep
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    """shard_map with varying-manual-axes checking off: the Pallas calls
+    inside the mapped function declare no ``vma`` on their outputs."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # --------------------------------------------------------------------------
@@ -161,7 +152,8 @@ class DistSF:
         return kops.segment_reduce_rows(
             sortedv, _take_row(p.red_seg_first, me),
             _take_row(p.red_seg_len, me), num_segments=p.red_nslots,
-            Lmax=p.red_Lmax, op=op.name, key=p.comm_signature())
+            Lmax=p.red_Lmax, op=op.name,
+            seg_of_slot=_take_row(p.red_seg_id, me), key=p.comm_signature())
 
     def _barrier(self, *xs):
         if len(xs) == 1:

@@ -105,10 +105,7 @@ def _tracing() -> bool:
     """Are we under a jax trace right now?  Hooks must never record wall
     time or execution counts from inside a trace."""
     import jax
-    try:
-        return not jax.core.trace_state_clean()
-    except AttributeError:        # pragma: no cover - jax API drift
-        return False
+    return not jax.core.trace_ctx.is_top_level()
 
 
 # --------------------------------------------------------------------------

@@ -28,6 +28,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tuning import resolve_interpret
+
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
@@ -92,9 +94,10 @@ def _make_kernel(scale: float, causal: bool, window, Sq: int, Skv: int,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: int | None = None,
                     scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True
+                    block_k: int = 128, interpret: bool = None
                     ) -> jnp.ndarray:
     """q: (Sq, H, D); k, v: (Skv, Hkv, D) with Hkv | H.  Returns (Sq, H, D)."""
+    interpret = resolve_interpret(interpret)
     Sq, H, D = (int(x) for x in q.shape)
     Skv, Hkv, _ = (int(x) for x in k.shape)
     rep = H // Hkv

@@ -2,10 +2,13 @@
 
 The SF hot-path entry points (``pack_rows``, ``segment_reduce_rows``,
 ``local_bcast_rows``) are *autotuned*: each has several candidate lowerings
-(pure-XLA gather/segment ops, the row-per-step DMA kernels, row-blocked
-vectorized kernels at several block sizes, the fused local-exchange kernel)
-and :mod:`repro.kernels.tuning` sweeps them once per problem signature,
-memoizing the winner so repeated exchanges never re-sweep or re-trace.
+(pure-XLA gather/segment ops, row-blocked DMA kernels at several block
+sizes, the fused local-exchange kernel) and :mod:`repro.kernels.tuning`
+sweeps them once per problem signature, memoizing the winner so repeated
+exchanges never re-sweep or re-trace.  A kernel is a candidate only where
+the shape rules of :mod:`repro.kernels.sf_pack` /
+:mod:`repro.kernels.sf_unpack` say it compiles (dtype, tile alignment,
+VMEM budget), so every candidate offered on a TPU compiles under Mosaic.
 
 Interpret-vs-compiled is decided in exactly one place —
 ``tuning.resolve_interpret`` (env override ``REPRO_SF_INTERPRET``, then
@@ -22,10 +25,11 @@ import numpy as np
 
 from . import ref, tuning
 from .flash_attention import flash_attention as _flash
-from .sf_pack import (bcast_fused as _bcast_fused, pack as _pack,
+from .sf_pack import (bcast_fused as _bcast_fused, kernel_dtype_ok,
+                      pack as _pack, pack_block_ok,
                       pack_blocked as _pack_blocked,
-                      pack_strided as _pack_strided)
-from .sf_unpack import (segment_reduce_blocked, segment_reduce_sorted,
+                      pack_strided as _pack_strided, strided_ok, sublane_rows)
+from .sf_unpack import (seg_block_ok, segment_reduce_blocked,
                         unpack_segments)
 from .spmv_ell import spmv_ell as _spmv_ell
 from .tuning import resolve_interpret
@@ -57,31 +61,36 @@ tuning.register_cache(_DISPATCH)
 # --------------------------------------------------------------------------
 # pack: tuned row gather
 # --------------------------------------------------------------------------
-def _pack_block_sizes(M: int) -> list:
-    cands = {min(M, b) for b in (8, 32, 128, 512)}
-    if M <= 2048:
-        cands.add(M)          # single grid step
-    return sorted(cands)
+def _blocks(n: int, sizes, t: int, single_step_max: int) -> list:
+    """Kernel block sizes for ``n`` rows: the usual sizes capped at ``n``,
+    plus one grid step for the whole problem when it is small, each
+    rounded up to whole tiles of ``t`` rows."""
+    cands = {min(n, b) for b in sizes}
+    if n <= single_step_max:
+        cands.add(n)
+    return sorted({-(-max(b, 1) // t) * t for b in cands})
 
 
-def _pack_candidates(M: int, interpret: bool) -> dict:
+def _pack_candidates(M: int, unit, dtype, interpret: bool) -> dict:
     impls = {"xla": lambda d, i: jnp.take(d, i, axis=0)}
-    for B in _pack_block_sizes(M):
-        impls[f"block:{B}"] = (
-            lambda d, i, B=B: _pack_blocked(d, i, block_rows=B,
-                                            interpret=interpret))
-    # the one-row-per-step DMA kernel: the design of record on TPU, but in
-    # interpret mode each grid step pays python-interpreter cost, so beyond
-    # a handful of rows it can only win a sweep by measurement noise
-    if not interpret or M <= 32:
-        impls["row"] = lambda d, i: _pack(d, i, interpret=interpret)
+    if not kernel_dtype_ok(dtype):
+        return impls
+    for B in _blocks(M, (8, 32, 128, 512), sublane_rows(dtype), 2048):
+        if pack_block_ok(B, unit, dtype):
+            impls[f"block:{B}"] = (
+                lambda d, i, B=B: _pack_blocked(d, i, block_rows=B,
+                                                interpret=interpret))
     return impls
 
 
-def _pack_default(M: int, interpret: bool) -> str:
-    if interpret:
-        return f"block:{min(M, 128)}"
-    return "row"
+def _kernel_default(impls: dict) -> str:
+    """Unswept default: the largest kernel block of at most 128 rows (the
+    smallest kept block if none is), else the XLA lowering."""
+    sizes = sorted(int(k[len("block:"):]) for k in impls
+                   if k.startswith("block:"))
+    if not sizes:
+        return "xla"
+    return f"block:{max([b for b in sizes if b <= 128] or sizes[:1])}"
 
 
 def pack_rows(data, idx, *, interpret=None, key=None):
@@ -98,7 +107,7 @@ def pack_rows(data, idx, *, interpret=None, key=None):
     # the sub-µs signature fast path: attribute lookups only, no jnp calls
     dshape = data.shape if hasattr(data, "shape") else np.shape(data)
     idx_shape = idx.shape if hasattr(idx, "shape") else np.shape(idx)
-    dts = np.dtype(getattr(data, "dtype", type(data))).str
+    dts = np.dtype(getattr(data, "dtype", type(data)))
     interpret = resolve_interpret(interpret)
     sig = ("pack", tuple(dshape), tuple(idx_shape), dts, interpret,
            _platform(), key)
@@ -121,12 +130,12 @@ def _pack_dispatch(sig, dshape, idx_shape, dts, interpret):
     scalar_rows = len(dshape) == 1
     kunit = unit if not scalar_rows else (1,)
     N, M = int(dshape[0]), n_idx
-    impls = _pack_candidates(M, interpret)
+    impls = _pack_candidates(M, kunit, dts, interpret)
     winner = tuning.autotune(
         "pack", (N, M, kunit, dts, interpret, _platform(), sig[-1]), impls,
         lambda: (jnp.zeros((N,) + kunit, dts),
                  jnp.arange(M, dtype=jnp.int32) % N),
-        default=_pack_default(M, interpret), work=M * usize)
+        default=_kernel_default(impls), work=M * usize)
     impl = impls[winner]
 
     @jax.jit
@@ -142,39 +151,21 @@ def _pack_dispatch(sig, dshape, idx_shape, dts, interpret):
 # --------------------------------------------------------------------------
 # segment reduce: tuned sorted-buffer reduction
 # --------------------------------------------------------------------------
-def _seg_block_sizes(S: int, Lmax: int) -> list:
-    cands = {min(S, b) for b in (8, 32, 128)}
-    if S <= 1024:
-        cands.add(S)          # single grid step
-    return sorted(b for b in cands if b * Lmax <= 65536) or [min(S, 8)]
-
-
-def _seg_candidates(S: int, Lmax: int, op: str, interpret: bool,
-                    have_ids: bool) -> dict:
-    def _padded(vals):
-        pad = jnp.zeros((Lmax,) + vals.shape[1:], vals.dtype)
-        return jnp.concatenate([vals, pad], axis=0)
-
+def _seg_candidates(S: int, Lmax: int, op: str, unit, dtype,
+                    interpret: bool, have_ids: bool) -> dict:
     impls = {}
-    for SB in _seg_block_sizes(S, Lmax):
-        impls[f"block:{SB}"] = (
-            lambda v, f, l, ids, SB=SB: segment_reduce_blocked(
-                _padded(v), f, l, num_segments=S, Lmax=Lmax,
-                segs_per_block=SB, op=op, interpret=interpret))
-    if not interpret or S <= 256:
-        impls["row"] = lambda v, f, l, ids: segment_reduce_sorted(
-            _padded(v), f, l, num_segments=S, Lmax=Lmax, op=op,
-            interpret=interpret)
     if have_ids:
         impls["xla"] = lambda v, f, l, ids: ref.unpack_segment_ref(
             v, ids, num_segments=S, op=op)
+    if not kernel_dtype_ok(dtype):
+        return impls
+    for SB in _blocks(S, (8, 32, 128), sublane_rows(dtype), 1024):
+        if seg_block_ok(SB, Lmax, unit, dtype):
+            impls[f"block:{SB}"] = (
+                lambda v, f, l, ids, SB=SB: segment_reduce_blocked(
+                    v, f, l, num_segments=S, Lmax=Lmax, segs_per_block=SB,
+                    op=op, interpret=interpret))
     return impls
-
-
-def _seg_default(S: int, interpret: bool) -> str:
-    if interpret:
-        return f"block:{min(S, 128)}"
-    return "row"
 
 
 def segment_reduce_rows(sorted_vals, seg_first, seg_len, *, num_segments,
@@ -193,7 +184,7 @@ def segment_reduce_rows(sorted_vals, seg_first, seg_len, *, num_segments,
     interpret = resolve_interpret(interpret)
     vshape = sorted_vals.shape if hasattr(sorted_vals, "shape") \
         else np.shape(sorted_vals)
-    dts = np.dtype(getattr(sorted_vals, "dtype", type(sorted_vals))).str
+    dts = np.dtype(getattr(sorted_vals, "dtype", type(sorted_vals)))
     have_ids = seg_of_slot is not None
     sig = ("segred", tuple(vshape), dts, int(num_segments), int(Lmax), op,
            have_ids, interpret, _platform(), key)
@@ -212,7 +203,10 @@ def _segred_dispatch(sig, vshape, dts, S, Lmax, op, have_ids, interpret):
     kunit = vshape[1:] if not scalar_rows else (1,)
     M = int(vshape[0])
     usize = int(np.prod(kunit)) if kunit else 1
-    impls = _seg_candidates(S, Lmax, op, interpret, have_ids)
+    impls = _seg_candidates(S, Lmax, op, kunit, dts, interpret, have_ids)
+    if not impls:
+        raise ValueError(f"no segment-reduce lowering serves {dts} rows "
+                         "without per-slot segment ids")
 
     def _synth_args():
         base, rem = divmod(M, max(S, 1))
@@ -228,7 +222,7 @@ def _segred_dispatch(sig, vshape, dts, S, Lmax, op, have_ids, interpret):
     winner = tuning.autotune(
         "segred", (M, S, Lmax, kunit, dts, op, interpret, have_ids,
                    _platform(), sig[-1]),
-        impls, _synth_args, default=_seg_default(S, interpret),
+        impls, _synth_args, default=_kernel_default(impls),
         work=M * usize)
     impl = impls[winner]
 
@@ -243,14 +237,16 @@ def _segred_dispatch(sig, vshape, dts, S, Lmax, op, have_ids, interpret):
 # --------------------------------------------------------------------------
 # fused local exchange: tuned leaf[gl] = root[gr]
 # --------------------------------------------------------------------------
-def _local_candidates(interpret: bool) -> dict:
+def _local_candidates(rdtype, ldtype, interpret: bool) -> dict:
     def _xla(root, leaf, gr, gl):
         return leaf.at[gl].set(jnp.take(root, gr, axis=0).astype(leaf.dtype),
                                unique_indices=True)
 
-    return {"xla": _xla,
-            "fused": lambda root, leaf, gr, gl: _bcast_fused(
-                root, leaf, gr, gl, interpret=interpret)}
+    impls = {"xla": _xla}
+    if kernel_dtype_ok(rdtype) and kernel_dtype_ok(ldtype):
+        impls["fused"] = lambda root, leaf, gr, gl: _bcast_fused(
+            root, leaf, gr, gl, interpret=interpret)
+    return impls
 
 
 def local_bcast_rows(rootdata, leafdata, gr, gl, *, interpret=None,
@@ -268,8 +264,8 @@ def local_bcast_rows(rootdata, leafdata, gr, gl, *, interpret=None,
     if E == 0:
         return jnp.asarray(leafdata)
     interpret = resolve_interpret(interpret)
-    rdts = np.dtype(getattr(rootdata, "dtype", type(rootdata))).str
-    ldts = np.dtype(getattr(leafdata, "dtype", type(leafdata))).str
+    rdts = np.dtype(getattr(rootdata, "dtype", type(rootdata)))
+    ldts = np.dtype(getattr(leafdata, "dtype", type(leafdata)))
     sig = ("localbcast", tuple(rshape), tuple(lshape), rdts, ldts, E,
            interpret, _platform(), key)
     fn = _DISPATCH.get(sig)
@@ -296,7 +292,7 @@ def _local_dispatch(sig, rshape, lshape, rdts, ldts, E, interpret):
         return jax.jit(_scatter)
     kunit = unit if not scalar_rows else (1,)
     Nr, Nl = int(rshape[0]), int(lshape[0])
-    impls = _local_candidates(interpret)
+    impls = _local_candidates(rdts, ldts, interpret)
     winner = tuning.autotune(
         "localbcast", (Nr, Nl, E, kunit, rdts, ldts, interpret, _platform(),
                        sig[-1]),
@@ -305,7 +301,7 @@ def _local_dispatch(sig, rshape, lshape, rdts, ldts, E, interpret):
                  jnp.zeros((Nl,) + kunit, ldts),
                  jnp.arange(E, dtype=jnp.int32) % Nr,
                  jnp.arange(E, dtype=jnp.int32) % Nl),
-        default="fused" if interpret else "xla", work=E * usize)
+        default="fused" if "fused" in impls else "xla", work=E * usize)
     impl = impls[winner]
 
     @jax.jit

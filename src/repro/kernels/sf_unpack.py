@@ -8,16 +8,18 @@ no global atomics and hates scattered stores, so the TPU-native design
   1. at *setup* time, sort the packed-slot order by destination row
      (amortized over every operation on the SF template, like all PetscSF
      index analysis);
-  2. at run time, a grid step loads a bounded panel of sorted rows and
-     reduces the runs belonging to each destination *segment* entirely in
-     VMEM/VREGs, emitting one dense row per segment;
+  2. at run time, a grid step fetches a bounded window of sorted rows per
+     segment and reduces each destination *segment* entirely in VMEM/VREGs,
+     emitting one dense row per segment;
   3. the caller scatters the per-segment results to their destination rows
      with a *duplicate-free* scatter (trivially deterministic).
 
 The kernel below implements step 2: a segment reduction over a sorted buffer
-with per-segment (start, length) metadata in scalar-prefetch SMEM.  Each grid
-step handles one segment; the panel height ``Lmax`` (max segment length,
-padded to the VPU sublane count) bounds the VMEM working set.
+with per-segment (start, length) metadata in SMEM.  The sorted buffer stays
+in HBM in the lane-dense row view of :func:`repro.kernels.sf_pack.row_view`;
+each segment's rows are DMA'd as the aligned window of whole ``t``-row tiles
+that covers them (``Lmax`` bounds the window), masked to the segment, and
+reduced in a 32-bit accumulator.
 
 Supported ops: sum, max, min, prod (replace is handled by the caller via the
 precomputed last-writer trick and never reaches this kernel).
@@ -33,117 +35,76 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .sf_pack import (VMEM_BUDGET, _acc_dtype, _chunked, _lane_width,
+                      _round_up, row_view, sublane_rows)
 from .tuning import resolve_interpret
 
 __all__ = ["unpack_segments", "segment_reduce_sorted",
-           "segment_reduce_blocked"]
+           "segment_reduce_blocked", "seg_window_rows", "seg_block_ok"]
 
-_INIT = {
-    "sum": lambda dt: jnp.zeros((), dt),
-    "prod": lambda dt: jnp.ones((), dt),
-    "max": lambda dt: jnp.array(-jnp.inf if jnp.issubdtype(dt, jnp.floating)
-                                else jnp.iinfo(dt).min, dt),
-    "min": lambda dt: jnp.array(jnp.inf if jnp.issubdtype(dt, jnp.floating)
-                                else jnp.iinfo(dt).max, dt),
-}
-
-_COMBINE = {
-    "sum": lambda a, b: a + b,
-    "prod": lambda a, b: a * b,
-    "max": jnp.maximum,
-    "min": jnp.minimum,
-}
+_REDUCE = {"sum": jnp.sum, "prod": jnp.prod, "max": jnp.max, "min": jnp.min}
 
 
-def _make_kernel(op: str, Lmax: int):
-    combine = _COMBINE[op]
-
-    def kernel(meta_ref, buf_ref, out_ref):
-        # meta_ref: (2, S) SMEM — row 0: segment start, row 1: segment length.
-        # buf_ref:  (Lmax, *unit) panel starting at this segment's first row.
-        s = pl.program_id(0)
-        length = meta_ref[1, s]
-        panel = buf_ref[...]
-        dt = panel.dtype
-        init = _INIT[op](dt)
-        rows = jax.lax.broadcasted_iota(jnp.int32, panel.shape, 0)
-        masked = jnp.where(rows < length, panel, init)
-        if op == "sum":
-            red = jnp.sum(masked, axis=0, keepdims=True)
-        elif op == "prod":
-            red = jnp.prod(masked, axis=0, keepdims=True)
-        elif op == "max":
-            red = jnp.max(masked, axis=0, keepdims=True)
-        else:
-            red = jnp.min(masked, axis=0, keepdims=True)
-        out_ref[...] = red.astype(dt)
-
-    return kernel
+def _identity(op: str, acc):
+    if op == "sum":
+        return 0
+    if op == "prod":
+        return 1
+    big = jnp.inf if acc == jnp.float32 else jnp.iinfo(jnp.int32).max
+    return -big if op == "max" else big
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_segments", "Lmax", "op", "interpret"))
-def segment_reduce_sorted(buf: jnp.ndarray, seg_start: jnp.ndarray,
-                          seg_len: jnp.ndarray, *, num_segments: int,
-                          Lmax: int, op: str = "sum", interpret: bool = None
-                          ) -> jnp.ndarray:
-    """Reduce sorted rows into per-segment rows.
-
-    buf:       (M, *unit) rows sorted by destination, any unit rank >= 1;
-               padded with >= Lmax extra rows so every panel load is in
-               bounds (caller pads).  The panel blocks over the full unit
-               extent, so multi-dim dof blocks reduce without flattening.
-    seg_start: (S,) first row of each segment.
-    seg_len:   (S,) segment length (<= Lmax).
-    Returns (num_segments, *unit).
-    """
-    interpret = resolve_interpret(interpret)
-    unit = tuple(int(d) for d in buf.shape[1:])
-    zeros = (0,) * len(unit)
-    meta = jnp.stack([seg_start.astype(jnp.int32),
-                      seg_len.astype(jnp.int32)], axis=0)
-    return pl.pallas_call(
-        _make_kernel(op, Lmax),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(num_segments,),
-            in_specs=[pl.BlockSpec((Lmax,) + unit,
-                                   lambda s, meta_ref: (meta_ref[0, s],)
-                                   + zeros,
-                                   indexing_mode=pl.unblocked)],
-            out_specs=pl.BlockSpec((1,) + unit,
-                                   lambda s, meta_ref: (s,) + zeros),
-        ),
-        out_shape=jax.ShapeDtypeStruct((num_segments,) + unit, buf.dtype),
-        interpret=interpret,
-    )(meta, buf)
+def seg_window_rows(Lmax: int, dtype) -> int:
+    """Rows of the whole-tile window that covers any segment of at most
+    ``Lmax`` rows, wherever it starts."""
+    t = sublane_rows(dtype)
+    return ((max(int(Lmax), 1) - 1) // t + 2) * t
 
 
-def _make_blocked_kernel(op: str, Lmax: int, segs_per_block: int,
-                         unit_rank: int):
-    def kernel(meta_ref, buf_ref, out_ref):
-        # meta_ref: (2, Spad) SMEM — row 0: segment first row, row 1: length.
-        s0 = pl.program_id(0) * segs_per_block
-        first = jax.lax.dynamic_slice(meta_ref[0], (s0,), (segs_per_block,))
-        length = jax.lax.dynamic_slice(meta_ref[1], (s0,), (segs_per_block,))
-        panel = buf_ref[...]
-        dt = panel.dtype
-        lane = jax.lax.broadcasted_iota(jnp.int32, (segs_per_block, Lmax), 1)
-        rows = first[:, None] + lane                 # (SB, Lmax) row gather
-        vals = jnp.take(panel, rows.reshape(-1), axis=0).reshape(
-            (segs_per_block, Lmax) + panel.shape[1:])
-        mask = (lane < length[:, None]).reshape(
-            (segs_per_block, Lmax) + (1,) * unit_rank)
-        masked = jnp.where(mask, vals, _INIT[op](dt))
-        if op == "sum":
-            red = jnp.sum(masked, axis=1)
-        elif op == "prod":
-            red = jnp.prod(masked, axis=1)
-        elif op == "max":
-            red = jnp.max(masked, axis=1)
-        else:
-            red = jnp.min(masked, axis=1)
-        out_ref[...] = red.astype(dt)
+def seg_block_ok(segs_per_block: int, Lmax: int, unit, dtype) -> bool:
+    """A segment block fits the VMEM budget: one window per segment."""
+    return (segs_per_block % sublane_rows(dtype) == 0
+            and segs_per_block * seg_window_rows(Lmax, dtype)
+            * _lane_width(unit) * np.dtype(dtype).itemsize <= VMEM_BUDGET)
+
+
+def _make_kernel(op: str, SB: int, t: int, W: int):
+    reduce = _REDUCE[op]
+
+    def kernel(meta_ref, buf_hbm, out_ref, win, sem):
+        # meta_ref: (2, chunk) SMEM — row 0: segment first row, row 1:
+        # length; this step reduces SB of the chunk's segments
+        base = pl.program_id(0) * SB
+
+        def dma(j):
+            g = meta_ref[0, base + j] // t
+            return pltpu.make_async_copy(
+                buf_hbm.at[pl.ds(pl.multiple_of(g * t, t), W)], win.at[j], sem)
+
+        jax.lax.fori_loop(0, SB, lambda j, c: (dma(j).start(), c)[1], 0)
+        jax.lax.fori_loop(0, SB, lambda j, c: (dma(j).wait(), c)[1], 0)
+
+        acc_dt = _acc_dtype(out_ref.dtype)
+        ident = _identity(op, acc_dt)
+        Up = out_ref.shape[1]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (W, Up), 0)
+        tile_rows = jax.lax.broadcasted_iota(jnp.int32, (t, Up), 0)
+
+        def tile(q, c):
+            acc = jnp.zeros((t, Up), acc_dt)
+            for jj in range(t):
+                j = q * t + jj
+                first = meta_ref[0, base + j]
+                off = first - first // t * t
+                inseg = (rows >= off) & (rows < off + meta_ref[1, base + j])
+                vals = jnp.where(inseg, win[j].astype(acc_dt), ident)
+                red = reduce(vals, axis=0, keepdims=True)
+                acc = jnp.where(tile_rows == jj, red, acc)
+            out_ref[pl.ds(pl.multiple_of(q * t, t), t), :] = \
+                acc.astype(out_ref.dtype)
+            return c
+
+        jax.lax.fori_loop(0, SB // t, tile, 0)
 
     return kernel
 
@@ -155,44 +116,58 @@ def segment_reduce_blocked(buf: jnp.ndarray, seg_start: jnp.ndarray,
                            seg_len: jnp.ndarray, *, num_segments: int,
                            Lmax: int, segs_per_block: int, op: str = "sum",
                            interpret: bool = None) -> jnp.ndarray:
-    """Segment-blocked variant of :func:`segment_reduce_sorted`: each grid
-    step reduces ``segs_per_block`` segments at once from the resident sorted
-    buffer — ``ceil(S / segs_per_block)`` steps instead of ``S``, amortizing
-    the per-step launch cost that dominates when segments are short.
+    """Reduce sorted rows into per-segment rows, ``segs_per_block``
+    segments per grid step (rounded up to a whole number of tiles).
 
-    Same contract as ``segment_reduce_sorted`` (buf padded with >= Lmax
-    rows; returns ``(num_segments, *unit)``).  Which block size wins — or
-    whether the per-segment panel-DMA variant / the XLA segment ops win —
-    is decided by the autotuner in :mod:`repro.kernels.tuning`.
+    buf:       (M, *unit) rows sorted by destination, any unit rank >= 1.
+    seg_start: (S,) first row of each segment.
+    seg_len:   (S,) segment length (<= Lmax); a zero-length segment emits
+               the op's identity.
+    Returns (num_segments, *unit).  Which block size wins — or whether the
+    XLA segment ops win — is decided by the autotuner in
+    :mod:`repro.kernels.tuning`.
     """
     interpret = resolve_interpret(interpret)
     S = int(num_segments)
-    SB = max(1, min(int(segs_per_block), S))
-    G = -(-S // SB)
-    Spad = G * SB
     unit = tuple(int(d) for d in buf.shape[1:])
-    zeros = (0,) * len(unit)
-    first = seg_start.astype(jnp.int32)
-    length = seg_len.astype(jnp.int32)
-    if Spad > S:
-        pad = jnp.zeros((Spad - S,), jnp.int32)
-        first = jnp.concatenate([first, pad])
-        length = jnp.concatenate([length, pad])   # len 0 -> emits identity
-    meta = jnp.stack([first, length], axis=0)
-    out = pl.pallas_call(
-        _make_blocked_kernel(op, Lmax, SB, len(unit)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(G,),
-            in_specs=[pl.BlockSpec(buf.shape,
-                                   lambda s, meta_ref: (0,) + zeros)],
-            out_specs=pl.BlockSpec((SB,) + unit,
-                                   lambda s, meta_ref: (s,) + zeros),
-        ),
-        out_shape=jax.ShapeDtypeStruct((Spad,) + unit, buf.dtype),
-        interpret=interpret,
-    )(meta, buf)
-    return out[:S] if Spad > S else out
+    U = int(np.prod(unit)) if unit else 1
+    t = sublane_rows(buf.dtype)
+    W = seg_window_rows(Lmax, buf.dtype)
+    SB = _round_up(max(1, min(int(segs_per_block), S)), t)
+    G = -(-S // SB)
+    x = row_view(buf, t, extra_rows=W)       # every window stays in bounds
+    Up = int(x.shape[1])
+    pad = G * SB - S
+    meta = jnp.stack([jnp.pad(seg_start.astype(jnp.int32), (0, pad)),
+                      jnp.pad(seg_len.astype(jnp.int32), (0, pad))], axis=0)
+
+    def call(chunk):
+        return pl.pallas_call(
+            _make_kernel(op, SB, t, W),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(chunk.shape[1] // SB,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((SB, Up), lambda s, meta_ref: (s, 0)),
+                scratch_shapes=[pltpu.VMEM((SB, W, Up), buf.dtype),
+                                pltpu.SemaphoreType.DMA(())]),
+            out_shape=jax.ShapeDtypeStruct((chunk.shape[1], Up), buf.dtype),
+            interpret=interpret,
+        )(chunk, x)
+
+    out = _chunked(call, meta, SB)
+    return out[:S, :U].reshape((S,) + unit)
+
+
+def segment_reduce_sorted(buf: jnp.ndarray, seg_start: jnp.ndarray,
+                          seg_len: jnp.ndarray, *, num_segments: int,
+                          Lmax: int, op: str = "sum", interpret: bool = None
+                          ) -> jnp.ndarray:
+    """:func:`segment_reduce_blocked` at the smallest block: one output
+    tile of segments per grid step."""
+    return segment_reduce_blocked(
+        buf, seg_start, seg_len, num_segments=num_segments, Lmax=Lmax,
+        segs_per_block=sublane_rows(buf.dtype), op=op, interpret=interpret)
 
 
 def unpack_segments(target: jnp.ndarray, buf_sorted: jnp.ndarray,
@@ -209,10 +184,7 @@ def unpack_segments(target: jnp.ndarray, buf_sorted: jnp.ndarray,
     if S == 0:
         return target
     Lmax = max(int(np.max(seg_len)), 1)
-    # pad buffer so the last panel load stays in bounds
-    pad = jnp.zeros((Lmax,) + buf_sorted.shape[1:], buf_sorted.dtype)
-    buf_p = jnp.concatenate([buf_sorted, pad], axis=0)
-    red = segment_reduce_sorted(buf_p, jnp.asarray(seg_start),
+    red = segment_reduce_sorted(buf_sorted, jnp.asarray(seg_start),
                                 jnp.asarray(seg_len), num_segments=S,
                                 Lmax=Lmax, op=op, interpret=interpret)
     at = target.at[seg_dst]

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tuning import resolve_interpret
+
 __all__ = ["spmv_ell"]
 
 
@@ -31,12 +33,13 @@ def _spmv_kernel(data_ref, cols_ref, x_ref, y_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def spmv_ell(data: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray, *,
-             block_rows: int = 256, interpret: bool = True) -> jnp.ndarray:
+             block_rows: int = 256, interpret: bool = None) -> jnp.ndarray:
     """y[i] = Σ_k data[i,k] * x[cols[i,k]].
 
     data/cols: (N, K); x: (Nx,) — the caller appends one trailing zero and
     points padding columns at it.  Returns (N,).
     """
+    interpret = resolve_interpret(interpret)
     N, K = (int(s) for s in data.shape)
     Bn = min(block_rows, N)
     N_p = ((N + Bn - 1) // Bn) * Bn

@@ -175,11 +175,13 @@ def autotune(kind: str, key: Key, candidates: Dict[str, Callable],
 
     ``candidates`` maps lowering name -> callable; ``make_args`` builds
     synthetic concrete arrays matching the problem signature (sweeps run
-    eagerly even when the caller is mid-trace under ``jax.jit``).  A
-    candidate that raises during the sweep — e.g. a lowering the platform's
-    compiler rejects — is disqualified, not fatal.  ``work`` (payload
+    eagerly even when the caller is mid-trace under ``jax.jit``).  Every
+    candidate must run: the candidate sets offer a kernel only where its
+    shape rules say it compiles, so a candidate that raises is a bug and
+    the sweep re-raises it (counted in ``stats()["candidate_errors"]``)
+    rather than letting another lowering win unnoticed.  ``work`` (payload
     elements) gates the sweep in auto mode; ``default`` is used when the
-    sweep is skipped or every candidate fails.
+    sweep is skipped.
     """
     full_key = (kind,) + tuple(key)
     winner = _WINNERS.get(full_key)
@@ -210,38 +212,34 @@ def autotune(kind: str, key: Key, candidates: Dict[str, Callable],
     iters = int(os.environ.get("REPRO_SF_TUNE_ITERS", "3"))
     rounds = int(os.environ.get("REPRO_SF_TUNE_ROUNDS", "3"))
     args = make_args()
+
+    def timed(name: str) -> float:
+        try:
+            return _time_candidate(candidates[name], args, iters)
+        except Exception as e:
+            _STATS["candidate_errors"] += 1
+            raise RuntimeError(
+                f"{kind} lowering {name!r} failed on {key}") from e
+
     # interleaved best-of-rounds: one timing window per candidate per round,
     # candidate's score = min over rounds.  A single load spike can land in
     # at most one window, so it can no longer crown a slow lowering (a
     # mis-pick is sticky for the whole process — worth the extra rounds)
     best: Dict[str, float] = {}
-    alive = dict(candidates)
     for _ in range(max(rounds, 1)):
-        for name in list(alive):
-            try:
-                t = _time_candidate(alive[name], args, iters)
-            except Exception:
-                _STATS["candidate_errors"] += 1
-                del alive[name]
-                best.pop(name, None)
-                continue
-            if t < best.get(name, float("inf")):
-                best[name] = t
-    if not best:                 # every candidate failed: fall back
-        best_name = default if default in candidates \
-            else next(iter(candidates))
-    else:
-        best_name = min(best, key=best.get)
-        if best_name != default and default in best:
-            # runoff: a mis-crowned winner is sticky for the whole process,
-            # so before dethroning the platform default re-time the two
-            # head-to-head in alternating windows (load spikes hit both)
-            tw = td = float("inf")
-            for _ in range(max(rounds, 1)):
-                tw = min(tw, _time_candidate(alive[best_name], args, iters))
-                td = min(td, _time_candidate(alive[default], args, iters))
-            if td <= tw:
-                best_name = default
+        for name in candidates:
+            best[name] = min(best.get(name, float("inf")), timed(name))
+    best_name = min(best, key=best.get)
+    if best_name != default and default in best:
+        # runoff: a mis-crowned winner is sticky for the whole process,
+        # so before dethroning the platform default re-time the two
+        # head-to-head in alternating windows (load spikes hit both)
+        tw = td = float("inf")
+        for _ in range(max(rounds, 1)):
+            tw = min(tw, timed(best_name))
+            td = min(td, timed(default))
+        if td <= tw:
+            best_name = default
     _STATS["sweeps"] += 1
     _WINNERS[full_key] = best_name
     return best_name

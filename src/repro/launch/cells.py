@@ -24,7 +24,7 @@ from ..models import transformer as T
 from ..models.config import ModelConfig
 from ..models.sharding import (batch_spec, cache_specs, dp_axes, param_specs,
                                shardings)
-from .mesh import make_mesh_compat
+from .mesh import make_small_mesh
 from ..training.optimizer import OptConfig, init_opt_state
 from ..training.train_loop import TrainConfig, make_train_step
 
@@ -214,7 +214,7 @@ def input_specs(arch: str, shape: str = "train_4k",
     weak-type-correct, shardable, no device allocation (the brief's
     ``input_specs()`` contract).  Returns the abstract argument tuple that
     ``build_cell(...)['fn'].lower(*input_specs(...))`` accepts."""
-    mesh = mesh or make_mesh_compat((1, 1), ("data", "model"))
+    mesh = mesh or make_small_mesh((1, 1), ("data", "model"))
     cell = build_cell(arch, shape, mesh)
     if cell is None:
         raise ValueError(f"cell ({arch}, {shape}) is skipped by design")

@@ -31,8 +31,7 @@ def main() -> int:
     from repro.configs import ALL_ARCHS, SHAPES, shape_supported
     from repro.launch.cells import build_cell
     from repro.launch.hlo_cost import analyze_hlo
-    from repro.launch.mesh import (make_production_mesh,
-                                       normalize_cost_analysis, use_mesh)
+    from repro.launch.mesh import make_production_mesh
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
@@ -74,14 +73,14 @@ def main() -> int:
                     continue
                 t0 = time.time()
                 try:
-                    with use_mesh(mesh):
+                    with jax.sharding.set_mesh(mesh):
                         cell = build_cell(arch, shape, mesh)
                         lowered = cell["fn"].lower(*cell["args"])
                         t_lower = time.time() - t0
                         compiled = lowered.compile()
                     t_compile = time.time() - t0 - t_lower
                     ma = compiled.memory_analysis()
-                    ca = normalize_cost_analysis(compiled.cost_analysis())
+                    ca = compiled.cost_analysis()
                     rec = {
                         "cell": tag, "status": "ok", "meta": cell["meta"],
                         "lower_s": round(t_lower, 1),

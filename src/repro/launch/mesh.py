@@ -9,52 +9,21 @@ state (the dry-run must set XLA_FLAGS before any jax initialization).
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_small_mesh", "make_mesh_compat",
-           "use_mesh", "normalize_cost_analysis", "HW"]
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer jax; on 0.4.x every mesh
-    axis is Auto-typed already, so the kwarg is simply dropped."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def use_mesh(mesh):
-    """``jax.sharding.set_mesh`` across jax versions.  Older jax has no
-    set_mesh; there the Mesh object itself is the context manager that makes
-    it current."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return contextlib.nullcontext() if mesh is None else mesh
-
-
-def normalize_cost_analysis(ca):
-    """``Compiled.cost_analysis()`` returns a per-partition list on jax
-    0.4.x and a flat dict on newer versions; normalize to the dict."""
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca
+__all__ = ["make_production_mesh", "make_small_mesh", "HW"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_small_mesh(shape=(2, 2), axes=("data", "model")):
     """Reduced mesh for CPU tests (requires enough host devices)."""
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 class HW:

@@ -51,7 +51,6 @@ def main() -> int:
     from repro.training.train_loop import (TrainConfig, TrainState,
                                            make_train_step)
     from repro.launch.cells import _opt_specs
-    from repro.launch.mesh import make_mesh_compat, use_mesh
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -64,7 +63,8 @@ def main() -> int:
     shape = ((args.pods, args.dp, args.tp) if args.pods > 1
              else (args.dp, args.tp))
     axes = (("pod", "data", "model") if args.pods > 1 else ("data", "model"))
-    mesh = make_mesh_compat(shape, axes)
+    mesh = jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
     ocfg = OptConfig(moments_dtype=args.moments, warmup_steps=10,
                      decay_steps=max(args.steps, 100))
@@ -92,7 +92,7 @@ def main() -> int:
             print(f"resumed at step {start}")
 
     import time
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         t0 = time.time()
         for i in range(start, args.steps):
             b = {k: jnp.asarray(v) for k, v in

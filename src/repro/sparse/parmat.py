@@ -134,7 +134,7 @@ class ParCSR:
 
     @staticmethod
     def from_dmda_stencil(da, coeffs: Optional[Sequence[float]] = None,
-                          dtype=np.float32) -> "ParCSR":
+                          dtype=np.float32, backend=None) -> "ParCSR":
         """Stencil operator on a :class:`repro.meshdist.dmda.DMDA` grid.
 
         One matrix row per grid cell (DMDA *global* ordering, so the row/col
@@ -143,7 +143,8 @@ class ParCSR:
         ``da.stencil_offsets()`` (center first); default is the
         row-sum-zero Laplacian: +deg at the center, -1 per neighbor.
         Off-domain neighbors of non-periodic boundaries are dropped
-        (homogeneous Dirichlet).
+        (homogeneous Dirichlet).  ``backend`` names the SpMV ghost SF's
+        backend (auto-selected when ``None``).
         """
         offs = da.stencil_offsets()
         if coeffs is None:
@@ -170,7 +171,7 @@ class ParCSR:
             np.concatenate(rows_l), np.concatenate(cols_l),
             np.concatenate(vals_l),
             row_offsets=da.owned_offsets, col_offsets=da.owned_offsets,
-            dtype=dtype)
+            dtype=dtype, backend=backend)
 
     @property
     def shape(self) -> Tuple[int, int]:

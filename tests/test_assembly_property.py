@@ -96,7 +96,7 @@ def test_stash_assembly_insert_order_invariant(case, rnd):
 
 @given(assembly_cases(exact_values=True))
 @settings(max_examples=10)
-def test_assemble_performs_exactly_one_reduce(case, monkeypatch_reduce=None):
+def test_assemble_performs_exactly_one_reduce(case):
     nranks, m, n, rows, cols, vals, src = case
     sp = Sparsity(nranks, m, n, rows, cols)
     asm = MatAssembler(sp)

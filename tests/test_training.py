@@ -129,8 +129,8 @@ def test_elastic_restore_different_sharding(cfg):
     st = TrainState.create(key, cfg, OptConfig())
     with tempfile.TemporaryDirectory() as d:
         save_checkpoint(d, 5, {"params": st.params}, extra={"step": 5})
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((1,), ("data",))
+        from repro.launch.mesh import make_small_mesh
+        mesh = make_small_mesh((1,), ("data",))
         from jax.sharding import NamedSharding, PartitionSpec as P
         sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), st.params)
         loaded, _ = load_checkpoint(d, 5, {"params": st.params},

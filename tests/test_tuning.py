@@ -97,27 +97,33 @@ def test_autotune_env_pin(monkeypatch):
 
 
 def test_autotune_disqualifies_raising_candidate(monkeypatch):
+    """A raising candidate is a bug in the candidate set: the sweep counts
+    it and re-raises instead of letting another lowering win unnoticed."""
     monkeypatch.setenv("REPRO_SF_AUTOTUNE", "1")
 
     def boom(x):
         raise RuntimeError("unsupported lowering")
 
-    w = tuning.autotune("k", ("boom",),
+    with pytest.raises(RuntimeError, match="lowering 'bad' failed") as exc:
+        tuning.autotune("k", ("boom",),
                         {"bad": boom, "good": lambda x: x + 1},
                         lambda: (jnp.zeros((4,)),), default="bad", work=1)
-    assert w == "good"
+    assert "unsupported lowering" in str(exc.value.__cause__)
     assert tuning.stats()["candidate_errors"] == 1
+    assert tuning.lookup(("k", "boom")) is None     # nothing memoized
 
 
 def test_autotune_all_fail_falls_back_to_default(monkeypatch):
+    """No fallback: a sweep whose every candidate fails raises."""
     monkeypatch.setenv("REPRO_SF_AUTOTUNE", "1")
 
     def boom(x):
         raise RuntimeError("nope")
 
-    w = tuning.autotune("k", ("allboom",), {"bad": boom},
+    with pytest.raises(RuntimeError, match="lowering 'bad' failed"):
+        tuning.autotune("k", ("allboom",), {"bad": boom},
                         lambda: (jnp.zeros((4,)),), default="bad", work=1)
-    assert w == "bad"
+    assert tuning.lookup(("k", "allboom")) is None
 
 
 # ------------------------------------------- tuned entry points: caching
