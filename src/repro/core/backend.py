@@ -238,14 +238,15 @@ class PallasBackend:
         unit = data.shape[1:]
         usize = int(np.prod(unit)) if unit else 1
         M = int(np.size(idx))
-        if M == 0 or usize == 0 or data.shape[0] == 0:
-            return jnp.take(data, jnp.asarray(idx), axis=0)
-        scalar_rows = data.ndim == 1
-        out = kops.sf_pack_strided(data[:, None] if scalar_rows else data,
-                                   start=strided.start, dims=strided.dims,
-                                   strides=strided.strides,
-                                   interpret=self.interpret)
-        return out[:, 0] if scalar_rows else out
+        with sflog.scope("sf.pack"):
+            if M == 0 or usize == 0 or data.shape[0] == 0:
+                return jnp.take(data, jnp.asarray(idx), axis=0)
+            scalar_rows = data.ndim == 1
+            out = kops.sf_pack_strided(
+                data[:, None] if scalar_rows else data, start=strided.start,
+                dims=strided.dims, strides=strided.strides,
+                interpret=self.interpret)
+            return out[:, 0] if scalar_rows else out
 
     def _segment_reduce(self, sorted_vals: jnp.ndarray, opname: str
                         ) -> jnp.ndarray:

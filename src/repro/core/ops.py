@@ -58,11 +58,19 @@ class PendingComm:
 
 def _apply_unique(target: jnp.ndarray, idx: np.ndarray, vals: jnp.ndarray,
                   op: Op) -> jnp.ndarray:
-    """Scatter ``vals`` into ``target`` at unique ``idx`` with reduction op."""
-    ref = target.at[idx]
-    return getattr(ref, op.at_update)(vals.astype(target.dtype),
-                                      unique_indices=True,
-                                      indices_are_sorted=False)
+    """Scatter ``vals`` into ``target`` at unique ``idx`` with reduction op
+    (the SF unpack: one named program, device scope ``sf.unpack``)."""
+    return sf_unpack_rows(target, idx, vals, mode=op.at_update)
+
+
+@partial(jax.jit, static_argnames="mode")
+def sf_unpack_rows(target: jnp.ndarray, idx, vals: jnp.ndarray, *,
+                   mode: str) -> jnp.ndarray:
+    """``target.at[idx].<mode>(vals)`` for unique ``idx``."""
+    with sflog.scope("sf.unpack"):
+        return getattr(target.at[idx], mode)(vals.astype(target.dtype),
+                                             unique_indices=True,
+                                             indices_are_sorted=False)
 
 
 class SFOps:
@@ -97,7 +105,8 @@ class SFOps:
         p = self.plan
         rootdata = jnp.asarray(rootdata)
         p.unit.check(rootdata, "rootdata")
-        vals = jnp.take(rootdata, p.gr, axis=0)   # pack == gather
+        with sflog.scope("sf.pack"):
+            vals = jnp.take(rootdata, p.gr, axis=0)   # pack == gather
         return PendingComm("bcast", vals, op, self)
 
     def bcast_end(self, pending: PendingComm, leafdata: jnp.ndarray) -> jnp.ndarray:
@@ -117,7 +126,8 @@ class SFOps:
         p = self.plan
         leafdata = jnp.asarray(leafdata)
         p.unit.check(leafdata, "leafdata")
-        vals = jnp.take(leafdata, p.gl, axis=0)
+        with sflog.scope("sf.pack"):
+            vals = jnp.take(leafdata, p.gl, axis=0)
         return PendingComm("reduce", vals, op, self)
 
     def reduce_end(self, pending: PendingComm, rootdata: jnp.ndarray) -> jnp.ndarray:
@@ -128,16 +138,20 @@ class SFOps:
         if op.name == "replace":
             # deterministic last-writer wins, precomputed at setup
             win_edges = p.red_perm[p.replace_last]
-            return rootdata.at[p.gr[win_edges]].set(
-                jnp.take(vals, win_edges, axis=0).astype(rootdata.dtype),
-                unique_indices=True)
+            with sflog.scope("sf.unpack"):
+                return rootdata.at[p.gr[win_edges]].set(
+                    jnp.take(vals, win_edges, axis=0).astype(rootdata.dtype),
+                    unique_indices=True)
         if op.name in ("sum", "prod", "max", "min"):
-            return getattr(rootdata.at[p.gr], op.at_update)(
-                vals.astype(rootdata.dtype))
+            # duplicate roots: the scatter combines as it writes
+            with sflog.scope("sf.unpack"):
+                return getattr(rootdata.at[p.gr], op.at_update)(
+                    vals.astype(rootdata.dtype))
         # logical ops: reduce via segment machinery for exactness
-        sorted_vals = jnp.take(vals, p.red_perm, axis=0)
-        seg = op.segment(sorted_vals, p.red_seg_of_edge,
-                         int(p.red_seg_root.shape[0]))
+        with sflog.scope("sf.combine"):
+            sorted_vals = jnp.take(vals, p.red_perm, axis=0)
+            seg = op.segment(sorted_vals, p.red_seg_of_edge,
+                             int(p.red_seg_root.shape[0]))
         return _apply_unique(rootdata, p.red_seg_root, seg, op)
 
     def reduce(self, leafdata, rootdata, op="sum"):

@@ -21,6 +21,15 @@ process-wide registry every SF consumer reports into:
 Rendering: :func:`log_view` (the PETSc-style text table) and
 :func:`dump_json` (a JSON-ready dict benchmarks stamp into artifacts).
 
+**Spans and scopes: the profiler's view.**  :func:`span` is a host span
+(``jax.profiler.TraceAnnotation``) on the profiler's clock: a no-op unless a
+trace is being captured, and also an event of this registry when logging is
+on, so ``-log_view`` and the trace name each thing once.  :func:`scope` is
+``jax.named_scope`` for code that runs under ``jit``: it costs nothing at
+run time and names every device op traced inside it (the op's ``tf_op``
+path in the trace).  Names are dotted ``<layer>.<part>``; the README section
+"Observability" lists those the program opens.
+
 **Trace safety.**  Instrumentation hooks fire at *dispatch* time — Python
 call boundaries — never inside a compiled program.  A hook that fires while
 ``jax.jit`` (or ``shard_map`` / ``lax.while_loop``) is tracing increments
@@ -41,12 +50,12 @@ always live: they are bare integer adds and pre-date this layer.
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import time
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Optional
 
+import jax
 import numpy as np
 
 __all__ = [
@@ -54,7 +63,7 @@ __all__ = [
     "Counter", "counter", "counters",
     "EventRecord", "event", "events",
     "op_begin", "op_end", "stash_pending", "claim_pending", "pending_end",
-    "timed", "context",
+    "span", "scope",
     "log_view", "dump_json", "events_snapshot", "events_delta",
     "overlap_efficiency", "exchange_totals",
     "sf_view", "format_sf_view",
@@ -197,7 +206,6 @@ class EventRecord:
 
 
 _EVENTS: Dict[str, EventRecord] = {}
-_CONTEXT: Dict[str, Any] = {}
 
 
 def event(name: str) -> EventRecord:
@@ -218,19 +226,6 @@ def reset(*, counters: bool = False) -> None:
     if counters:
         for c in _COUNTERS.values():
             c.value = 0
-
-
-@contextlib.contextmanager
-def context(**kv) -> Iterator[None]:
-    """Tag every event recorded in this scope with ``kv`` (request id, train
-    step, ...).  Values land in the events' bounded tag maps."""
-    old = dict(_CONTEXT)
-    _CONTEXT.update(kv)
-    try:
-        yield
-    finally:
-        _CONTEXT.clear()
-        _CONTEXT.update(old)
 
 
 # --------------------------------------------------------------------------
@@ -267,8 +262,6 @@ def op_end(name: str, t0: float, out=None, *, nbytes: float = 0.0,
     if tags:
         for k, v in tags.items():
             ev.tag(k, v)
-    for k, v in _CONTEXT.items():
-        ev.tag(k, v)
 
 
 def stash_pending(tok, end_name: str, nbytes: float,
@@ -320,23 +313,48 @@ def pending_end(info, t0: float, out=None) -> None:
     if tags:
         for k, v in tags.items():
             ev.tag(k, v)
-    for k, v in _CONTEXT.items():
-        ev.tag(k, v)
 
 
-@contextlib.contextmanager
-def timed(name: str, *, nbytes: float = 0.0,
-          tags: Optional[Dict[str, Any]] = None) -> Iterator[None]:
-    """Record the body as one event execution (no fencing of a result —
-    fence inside the body if needed)."""
+def span(name: str, **meta):
+    """Host span ``name`` with metadata ``meta``: a context manager.
+
+    Always a ``jax.profiler.TraceAnnotation(name, **meta)`` (a host event on
+    the profiler's clock, free unless a trace is being captured); when
+    logging is on, also one execution of the event ``name`` with ``meta`` as
+    its tags (``traced`` instead, when entered under a jax trace).  With
+    logging off it is the bare annotation: nothing else runs."""
     if _MODE == _OFF:
-        yield
-        return
-    t0 = op_begin()
-    try:
-        yield
-    finally:
-        op_end(name, t0, None, nbytes=nbytes, tags=tags)
+        return jax.profiler.TraceAnnotation(name, **meta)
+    return _LoggedSpan(name, meta)
+
+
+class _LoggedSpan:
+    """:func:`span` with logging on: the annotation plus one event."""
+
+    __slots__ = ("_ann", "_name", "_meta", "_t0")
+
+    def __init__(self, name: str, meta: Dict[str, Any]):
+        self._ann = jax.profiler.TraceAnnotation(name, **meta)
+        self._name = name
+        self._meta = meta
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_LoggedSpan":
+        self._ann.__enter__()
+        self._t0 = op_begin()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        op_end(self._name, self._t0, None, tags=self._meta)
+        self._ann.__exit__(*exc)
+
+
+def scope(name: str):
+    """Device scope ``name`` (``jax.named_scope``): every op traced inside
+    carries it on its name path, so the profiler's trace credits the op's
+    device time to it.  Free at run time; only code traced under ``jit``
+    (or inside a jitted helper) is named."""
+    return jax.named_scope(name)
 
 
 # --------------------------------------------------------------------------

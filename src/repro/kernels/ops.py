@@ -54,8 +54,17 @@ def _platform() -> str:
 # Per-signature jitted dispatch closures: once the autotuner has picked a
 # winner, repeat calls must cost one jit dispatch — the eager asarray /
 # reshape plumbing around the winner otherwise dominates small exchanges.
+# Each is named for its trace (``jit(sf_pack_rows)``, ...) and opens its SF
+# device scope (``sf.pack``, ``sf.combine``, ``sf.unpack``).
 _DISPATCH: dict = {}
 tuning.register_cache(_DISPATCH)
+
+
+def _scope(name: str):
+    """``sflog.scope``; imported at trace time, since ``repro.core`` imports
+    this module while it initialises."""
+    from ..core import sflog
+    return sflog.scope(name)
 
 
 # --------------------------------------------------------------------------
@@ -126,7 +135,10 @@ def _pack_dispatch(sig, dshape, idx_shape, dts, interpret):
     usize = int(np.prod(unit)) if unit else 1
     n_idx = int(np.prod(idx_shape)) if idx_shape else 1
     if usize == 0 or n_idx == 0 or dshape[0] == 0:
-        return jax.jit(lambda d, i: jnp.take(d, i, axis=0))
+        def sf_pack_rows(d, i):
+            with _scope("sf.pack"):
+                return jnp.take(d, i, axis=0)
+        return jax.jit(sf_pack_rows)
     scalar_rows = len(dshape) == 1
     kunit = unit if not scalar_rows else (1,)
     N, M = int(dshape[0]), n_idx
@@ -138,14 +150,14 @@ def _pack_dispatch(sig, dshape, idx_shape, dts, interpret):
         default=_kernel_default(impls), work=M * usize)
     impl = impls[winner]
 
-    @jax.jit
-    def fn(d, i):
-        out = impl(d[:, None] if scalar_rows else d, i.reshape(-1))
-        if scalar_rows:
-            out = out[:, 0]
-        return out.reshape(idx_shape + unit)
+    def sf_pack_rows(d, i):
+        with _scope("sf.pack"):
+            out = impl(d[:, None] if scalar_rows else d, i.reshape(-1))
+            if scalar_rows:
+                out = out[:, 0]
+            return out.reshape(idx_shape + unit)
 
-    return fn
+    return jax.jit(sf_pack_rows)
 
 
 # --------------------------------------------------------------------------
@@ -226,12 +238,12 @@ def _segred_dispatch(sig, vshape, dts, S, Lmax, op, have_ids, interpret):
         work=M * usize)
     impl = impls[winner]
 
-    @jax.jit
-    def fn(v, f, l, ids):
-        out = impl(v[:, None] if scalar_rows else v, f, l, ids)
-        return out[:, 0] if scalar_rows else out
+    def sf_segment_reduce(v, f, l, ids):
+        with _scope("sf.combine"):
+            out = impl(v[:, None] if scalar_rows else v, f, l, ids)
+            return out[:, 0] if scalar_rows else out
 
-    return fn
+    return jax.jit(sf_segment_reduce)
 
 
 # --------------------------------------------------------------------------
@@ -283,13 +295,14 @@ def _local_dispatch(sig, rshape, lshape, rdts, ldts, E, interpret):
     usize = int(np.prod(unit)) if unit else 1
     scalar_rows = len(lshape) == 1
 
-    def _scatter(root, leaf, gr, gl):
-        return leaf.at[gl.reshape(-1)].set(
-            jnp.take(root, gr.reshape(-1), axis=0).astype(leaf.dtype),
-            unique_indices=True)
+    def sf_local_bcast(root, leaf, gr, gl):
+        with _scope("sf.unpack"):
+            return leaf.at[gl.reshape(-1)].set(
+                jnp.take(root, gr.reshape(-1), axis=0).astype(leaf.dtype),
+                unique_indices=True)
 
     if usize == 0 or rshape[0] == 0 or lshape[0] == 0:
-        return jax.jit(_scatter)
+        return jax.jit(sf_local_bcast)
     kunit = unit if not scalar_rows else (1,)
     Nr, Nl = int(rshape[0]), int(lshape[0])
     impls = _local_candidates(rdts, ldts, interpret)
@@ -304,14 +317,15 @@ def _local_dispatch(sig, rshape, lshape, rdts, ldts, E, interpret):
         default="fused" if "fused" in impls else "xla", work=E * usize)
     impl = impls[winner]
 
-    @jax.jit
-    def fn(root, leaf, gr, gl):
-        if scalar_rows:
-            root, leaf = root[:, None], leaf[:, None]
-        out = impl(root, leaf, gr.reshape(-1), gl.reshape(-1))
-        return out[:, 0] if scalar_rows else out
+    def sf_local_bcast(root, leaf, gr, gl):
+        # the fused pack -> scatter writes the destination: an unpack
+        with _scope("sf.unpack"):
+            if scalar_rows:
+                root, leaf = root[:, None], leaf[:, None]
+            out = impl(root, leaf, gr.reshape(-1), gl.reshape(-1))
+            return out[:, 0] if scalar_rows else out
 
-    return fn
+    return jax.jit(sf_local_bcast)
 
 
 # --------------------------------------------------------------------------

@@ -93,7 +93,8 @@ class _StatCounters:
     be circular.
     """
 
-    _KEYS = ("sweeps", "hits", "defaults", "pinned", "candidate_errors")
+    _KEYS = ("sweeps", "hits", "defaults", "pinned", "candidate_errors",
+             "sweep_ns")
 
     def __init__(self):
         self._c = None
@@ -125,7 +126,8 @@ _MIN_TUNE_WORK = 4096
 
 
 def stats() -> Dict[str, int]:
-    """Counters for tests and diagnostics (sweeps run, cache hits, ...)."""
+    """Counters for tests and diagnostics (sweeps run, cache hits, ...;
+    ``sweep_ns`` is the wall time of every sweep, summed)."""
     return dict(_STATS)
 
 
@@ -209,6 +211,7 @@ def autotune(kind: str, key: Key, candidates: Dict[str, Callable],
         _WINNERS[full_key] = winner
         return winner
 
+    t_sweep = time.perf_counter_ns()
     iters = int(os.environ.get("REPRO_SF_TUNE_ITERS", "3"))
     rounds = int(os.environ.get("REPRO_SF_TUNE_ROUNDS", "3"))
     args = make_args()
@@ -241,5 +244,6 @@ def autotune(kind: str, key: Key, candidates: Dict[str, Callable],
         if td <= tw:
             best_name = default
     _STATS["sweeps"] += 1
+    _STATS["sweep_ns"] += time.perf_counter_ns() - t_sweep
     _WINNERS[full_key] = best_name
     return best_name

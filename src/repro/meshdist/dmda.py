@@ -28,18 +28,28 @@ within the box) in rank order — the layout of global SF arrays.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Dict, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import SFComm, StarForest, ragged_offsets
+from ..core import SFComm, StarForest, ragged_offsets, sflog
 from ..core.mpiops import get_op
 
 __all__ = ["DMDA", "default_proc_grid"]
 
 STAR = "star"
 BOX = "box"
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def dmda_fill(scope: str, shape, dtype, value):
+    """A destination array filled with ``value``, under device scope
+    ``scope`` (one named program instead of an unnamed eager fill)."""
+    with sflog.scope(scope):
+        return jnp.full(shape, value, dtype)
 
 
 def default_proc_grid(shape: Sequence[int], nranks: int) -> Tuple[int, ...]:
@@ -324,15 +334,17 @@ class DMDA:
         via SFBcast; in ``interior='skip'`` mode the owned block is a direct
         copy and the SF moves pure halo traffic).  ``gvec`` is
         ``(nglobal, *unit)``; returns ``(nlocal_total, *unit)``."""
-        gvec = jnp.asarray(gvec)
-        if lvec is None:
-            lvec = jnp.zeros((self.nlocal_total,) + gvec.shape[1:],
-                             gvec.dtype)
-        lvec = jnp.asarray(lvec)
-        if self.interior == "skip" and self._interior_dst.size:
-            lvec = lvec.at[self._interior_dst].set(
-                gvec[self._interior_src], unique_indices=True)
-        return self.comm(backend).bcast(gvec, lvec, "replace")
+        with sflog.span("dmda.g2l"), sflog.scope("dmda.g2l"):
+            gvec = jnp.asarray(gvec)
+            if lvec is None:
+                lvec = dmda_fill("dmda.g2l",
+                                 (self.nlocal_total,) + gvec.shape[1:],
+                                 gvec.dtype, 0)
+            lvec = jnp.asarray(lvec)
+            if self.interior == "skip" and self._interior_dst.size:
+                lvec = lvec.at[self._interior_dst].set(
+                    gvec[self._interior_src], unique_indices=True)
+            return self.comm(backend).bcast(gvec, lvec, "replace")
 
     def local_to_global(self, lvec, gvec=None, op="sum",
                         backend: Optional[str] = None):
@@ -340,16 +352,19 @@ class DMDA:
         owners — the assembly reduce of FD/FV stencil evaluation.  The
         default destination is the op's identity (not zeros: max/min/prod
         would otherwise clamp toward 0)."""
-        lvec = jnp.asarray(lvec)
-        if gvec is None:
-            gvec = jnp.full((self.nglobal,) + lvec.shape[1:],
-                            get_op(op).identity_of(lvec.dtype), lvec.dtype)
-        out = self.comm(backend).reduce(lvec, jnp.asarray(gvec), op)
-        if self.interior == "skip" and self._interior_dst.size:
-            o = get_op(op)
-            out = getattr(out.at[self._interior_src], o.at_update)(
-                lvec[self._interior_dst].astype(out.dtype))
-        return out
+        with sflog.span("dmda.l2g"), sflog.scope("dmda.l2g"):
+            lvec = jnp.asarray(lvec)
+            if gvec is None:
+                gvec = dmda_fill("dmda.l2g",
+                                 (self.nglobal,) + lvec.shape[1:],
+                                 lvec.dtype,
+                                 get_op(op).identity_of(lvec.dtype).item())
+            out = self.comm(backend).reduce(lvec, jnp.asarray(gvec), op)
+            if self.interior == "skip" and self._interior_dst.size:
+                o = get_op(op)
+                out = getattr(out.at[self._interior_src], o.at_update)(
+                    lvec[self._interior_dst].astype(out.dtype))
+            return out
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return (f"DMDA(shape={self.shape}, procs={self.proc_grid}, "

@@ -17,6 +17,7 @@ import numpy as np
 
 from .config import ModelConfig
 from .sharding import constrain
+from ..core import sflog
 
 __all__ = ["rmsnorm", "rope", "attention", "attention_decode", "mlp",
            "init_attn", "init_mlp", "cross_attention"]
@@ -152,27 +153,33 @@ def attention(x: jnp.ndarray, p: Dict, cfg: ModelConfig, *,
     Returns (output, (k, v)) so prefill can seed the KV cache.
     ``kv_override`` feeds encoder K/V for cross-attention.
     """
-    B, S, D = x.shape
-    H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    q = constrain((x @ p["wq"]).reshape(B, S, H, hd), model_dim=2)
-    if kv_override is None:
-        k = constrain((x @ p["wk"]).reshape(B, S, Hkv, hd), model_dim=2)
-        v = constrain((x @ p["wv"]).reshape(B, S, Hkv, hd), model_dim=2)
-    else:
-        k, v = kv_override
-    if cfg.qk_norm:
-        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, p["k_norm"], cfg.norm_eps) if kv_override is None else k
-    if positions is None:
-        positions = jnp.arange(S)
-    if kv_override is None:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-    Skv = k.shape[1]
-    out = _chunked_attn(q, k, v, qpos0=Skv - S if kv_override is None else 0,
-                        causal=causal, window=window, chunk=min(chunk, Skv))
-    out = constrain(out, model_dim=2)
-    return constrain(out.reshape(B, S, H * hd) @ p["wo"]), (k, v)
+    with sflog.scope("model.attn"):
+        B, S, D = x.shape
+        H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+        q = constrain((x @ p["wq"]).reshape(B, S, H, hd), model_dim=2)
+        if kv_override is None:
+            k = constrain((x @ p["wk"]).reshape(B, S, Hkv, hd),
+                          model_dim=2)
+            v = constrain((x @ p["wv"]).reshape(B, S, Hkv, hd),
+                          model_dim=2)
+        else:
+            k, v = kv_override
+        if cfg.qk_norm:
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+            if kv_override is None:
+                k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
+        if positions is None:
+            positions = jnp.arange(S)
+        if kv_override is None:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
+        Skv = k.shape[1]
+        out = _chunked_attn(q, k, v,
+                            qpos0=Skv - S if kv_override is None else 0,
+                            causal=causal, window=window,
+                            chunk=min(chunk, Skv))
+        out = constrain(out, model_dim=2)
+        return constrain(out.reshape(B, S, H * hd) @ p["wo"]), (k, v)
 
 
 def cross_attention(x, p, cfg: ModelConfig, enc_kv):
@@ -185,38 +192,41 @@ def attention_decode(x: jnp.ndarray, p: Dict, cfg: ModelConfig, cache_k,
                      chunk: int = 2048):
     """Single-token decode: x (B, 1, D); cache_k/v (B, Smax, Hkv, hd);
     pos: () current absolute position.  Returns (out, cache_k', cache_v')."""
-    B, _, D = x.shape
-    H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    q = (x @ p["wq"]).reshape(B, 1, H, hd)
-    k = (x @ p["wk"]).reshape(B, 1, Hkv, hd)
-    v = (x @ p["wv"]).reshape(B, 1, Hkv, hd)
-    if cfg.qk_norm:
-        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    q = rope(q, pos[None], cfg.rope_theta)
-    k = rope(k, pos[None], cfg.rope_theta)
-    cache_k = jax.lax.dynamic_update_slice(
-        cache_k, k.astype(cache_k.dtype), (0, pos.astype(jnp.int32), 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(
-        cache_v, v.astype(cache_v.dtype), (0, pos.astype(jnp.int32), 0, 0))
-    Smax = cache_k.shape[1]
-    rep = H // Hkv
-    scale = 1.0 / np.sqrt(hd)
-    # grouped-query attention WITHOUT materializing the repeated (or fp32)
-    # cache: q regrouped to (B, Hkv, rep, hd), contractions in fp32 via
-    # preferred_element_type (memory term stays 2 bytes/cache element)
-    qg = q.reshape(B, Hkv, rep, hd)
-    s = jnp.einsum("bkrd,bskd->bkrs", qg, cache_k,
-                   preferred_element_type=jnp.float32) * scale
-    kpos = jnp.arange(Smax)
-    mask = kpos <= pos
-    if window is not None:
-        mask &= kpos > pos - window
-    s = jnp.where(mask[None, None, None, :], s, -1e30)
-    pr = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkrs,bskd->bkrd", pr.astype(cache_v.dtype), cache_v,
-                     preferred_element_type=jnp.float32).astype(x.dtype)
-    return out.reshape(B, 1, H * hd) @ p["wo"], cache_k, cache_v
+    with sflog.scope("model.attn"):
+        B, _, D = x.shape
+        H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+        q = (x @ p["wq"]).reshape(B, 1, H, hd)
+        k = (x @ p["wk"]).reshape(B, 1, Hkv, hd)
+        v = (x @ p["wv"]).reshape(B, 1, Hkv, hd)
+        if cfg.qk_norm:
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+            k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
+        q = rope(q, pos[None], cfg.rope_theta)
+        k = rope(k, pos[None], cfg.rope_theta)
+        at = (0, pos.astype(jnp.int32), 0, 0)
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, k.astype(cache_k.dtype), at)
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, v.astype(cache_v.dtype), at)
+        Smax = cache_k.shape[1]
+        rep = H // Hkv
+        scale = 1.0 / np.sqrt(hd)
+        # grouped-query attention WITHOUT materializing the repeated (or
+        # fp32) cache: q regrouped to (B, Hkv, rep, hd), contractions in fp32
+        # via preferred_element_type (memory term stays 2 bytes/cache
+        # element)
+        qg = q.reshape(B, Hkv, rep, hd)
+        s = jnp.einsum("bkrd,bskd->bkrs", qg, cache_k,
+                       preferred_element_type=jnp.float32) * scale
+        kpos = jnp.arange(Smax)
+        mask = kpos <= pos
+        if window is not None:
+            mask &= kpos > pos - window
+        s = jnp.where(mask[None, None, None, :], s, -1e30)
+        pr = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bkrs,bskd->bkrd", pr.astype(cache_v.dtype), cache_v,
+                         preferred_element_type=jnp.float32).astype(x.dtype)
+        return out.reshape(B, 1, H * hd) @ p["wo"], cache_k, cache_v
 
 
 # --------------------------------------------------------------------------

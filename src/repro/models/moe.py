@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .config import ModelConfig
+from ..core import sflog
 from ..core.dynplan import DynPlan, PlanCache
 from ..core.fields import FieldBundle
 
@@ -162,84 +163,92 @@ def moe_layer(x: jnp.ndarray, p: Dict, cfg: ModelConfig, *,
     E, k = cfg.moe_experts, cfg.moe_topk
     G = groups if groups is not None else (B if S > 1 else 1)
     T = (B * S) // G
-    xg = constrain(x.reshape(G, T, D))
-
-    logits = constrain(jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
-                                  p["router"]))
-    probs = jax.nn.softmax(logits, axis=-1)
-    wk, eidx = jax.lax.top_k(probs, k)                  # (G, T, k)
-    wk = (wk / jnp.sum(wk, axis=-1, keepdims=True)).astype(x.dtype)
-
     C = max(int(np.ceil(T * k * cfg.moe_capacity / E)), 1)
 
-    slot, keep = jax.vmap(lambda e1: _capacity_slots(e1, C, E))(eidx)
+    with sflog.scope("moe.route"):
+        xg = constrain(x.reshape(G, T, D))
+        logits = constrain(jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
+                                      p["router"]))
+        probs = jax.nn.softmax(logits, axis=-1)
+        wk, eidx = jax.lax.top_k(probs, k)              # (G, T, k)
+        wk = (wk / jnp.sum(wk, axis=-1, keepdims=True)).astype(x.dtype)
+        slot, keep = jax.vmap(lambda e1: _capacity_slots(e1, C, E))(eidx)
+        if mode == "sf":
+            plan = _moe_plan(G, T, k, E, C, D, x.dtype)
+            leaf_root = routing_leaf_root(slot, keep, C, E)
 
-    if mode == "sf":
-        plan = _moe_plan(G, T, k, E, C, D, x.dtype)
-        leaf_root = routing_leaf_root(slot, keep, C, E)
-        w_leaf = wk.reshape(G * T * k, 1)
-        # capacity slots never repeat -> one writer per root, so the
-        # reduce lowers as invert-permutation + tuned gather (unique=True)
-        if G * T * k <= _FUSE_MAX_LEAVES:
-            # decode-sized: leaves carry the pick's hidden state + its
-            # combine weight; same dtype -> FieldBundle fuses both into
-            # ONE drop-guarded exchange
-            x_leaf = jnp.repeat(xg.reshape(G * T, D), k, axis=0)
-            bound = plan.bind(leaf_root, unique=True)
-            fb = FieldBundle.for_data(bound, [x_leaf, w_leaf])
-            buf, sw = fb.reduce_multi(
-                [x_leaf, w_leaf],
-                [jnp.zeros((G * E * C, D), x.dtype),
-                 jnp.zeros((G * E * C, 1), x.dtype)], op="sum")
+    with sflog.scope("moe.dispatch"):
+        if mode == "sf":
+            w_leaf = wk.reshape(G * T * k, 1)
+            # capacity slots never repeat -> one writer per root, so the
+            # reduce lowers as invert-permutation + tuned gather
+            # (unique=True)
+            if G * T * k <= _FUSE_MAX_LEAVES:
+                # decode-sized: leaves carry the pick's hidden state + its
+                # combine weight; same dtype -> FieldBundle fuses both into
+                # ONE drop-guarded exchange
+                x_leaf = jnp.repeat(xg.reshape(G * T, D), k, axis=0)
+                bound = plan.bind(leaf_root, unique=True)
+                fb = FieldBundle.for_data(bound, [x_leaf, w_leaf])
+                buf, sw = fb.reduce_multi(
+                    [x_leaf, w_leaf],
+                    [jnp.zeros((G * E * C, D), x.dtype),
+                     jnp.zeros((G * E * C, 1), x.dtype)], op="sum")
+            else:
+                # prefill-sized: the materialized repeat+concat dominates,
+                # so compose the exchange with the token->pick replication
+                # map instead (leaf_rep, the PetscSFCompose shortcut) and
+                # gather the hidden state straight from the compact token
+                # rows; the weight payload shares the same inverted-writer
+                # plan (CSE'd under jit into one inversion)
+                buf = plan.reduce(xg.reshape(G * T, D), leaf_root, op="sum",
+                                  unique=True, leaf_rep=k)
+                sw = plan.reduce(w_leaf, leaf_root, op="sum", unique=True)
         else:
-            # prefill-sized: the materialized repeat+concat dominates, so
-            # compose the exchange with the token->pick replication map
-            # instead (leaf_rep, the PetscSFCompose shortcut) and gather
-            # the hidden state straight from the compact token rows; the
-            # weight payload shares the same inverted-writer plan (CSE'd
-            # under jit into one inversion)
-            buf = plan.reduce(xg.reshape(G * T, D), leaf_root, op="sum",
-                              unique=True, leaf_rep=k)
-            sw = plan.reduce(w_leaf, leaf_root, op="sum", unique=True)
-        h = constrain(buf.reshape(G, E, C, D), model_dim=1)   # EP layout
-    else:
-        buf = _dispatch_dense(xg, slot, keep, C, E)
+            buf = _dispatch_dense(xg, slot, keep, C, E)
         h = constrain(buf.reshape(G, E, C, D), model_dim=1)   # EP layout
 
-    up = jnp.einsum("gecd,edf->gecf", h, p["w_in"])
-    gate = jnp.einsum("gecd,edf->gecf", h, p["w_gate"])
-    out = jnp.einsum("gecf,efd->gecd", jax.nn.silu(gate) * up, p["w_out"])
-    out_flat = constrain(out.reshape(G, E * C, D))
+    with sflog.scope("moe.experts"):
+        up = jnp.einsum("gecd,edf->gecf", h, p["w_in"])
+        gate = jnp.einsum("gecd,edf->gecf", h, p["w_gate"])
+        out = jnp.einsum("gecf,efd->gecd", jax.nn.silu(gate) * up,
+                         p["w_out"])
+        out_flat = constrain(out.reshape(G, E * C, D))
 
-    if mode == "sf":
-        # weight at the root (each slot has exactly one writer, so w*out
-        # here is bit-identical to weighting at the leaf), then bcast back:
-        # dropped picks read the zero drop row.  Sum over k as unrolled
-        # slice adds — XLA lowers this ~3x faster than reduce over the k
-        # axis at these shapes.
-        scaled = out_flat.reshape(G * E * C, D) * sw
-        picks = plan.bcast(scaled, leaf_root).reshape(G, T, k, D)
-        y = picks[:, :, 0]
-        for j in range(1, k):
-            y = y + picks[:, :, j]
-        y = y.reshape(B, S, D)
-    else:
-        def combine(of, slot1, keep1, w1):
-            gathered = of[jnp.minimum(slot1, E * C - 1)]      # (T, k, D)
-            gathered = gathered * keep1[..., None].astype(of.dtype)
-            return jnp.einsum("tkd,tk->td", gathered, w1.astype(of.dtype))
+    with sflog.scope("moe.combine"):
+        if mode == "sf":
+            # weight at the root (each slot has exactly one writer, so w*out
+            # here is bit-identical to weighting at the leaf), then bcast
+            # back: dropped picks read the zero drop row.  Sum over k as
+            # unrolled slice adds — XLA lowers this ~3x faster than reduce
+            # over the k axis at these shapes.
+            scaled = out_flat.reshape(G * E * C, D) * sw
+            picks = plan.bcast(scaled, leaf_root).reshape(G, T, k, D)
+            y = picks[:, :, 0]
+            for j in range(1, k):
+                y = y + picks[:, :, j]
+            y = y.reshape(B, S, D)
+        else:
+            def combine(of, slot1, keep1, w1):
+                gathered = of[jnp.minimum(slot1, E * C - 1)]  # (T, k, D)
+                gathered = gathered * keep1[..., None].astype(of.dtype)
+                return jnp.einsum("tkd,tk->td", gathered,
+                                  w1.astype(of.dtype))
 
-        y = jax.vmap(combine)(out_flat, slot, keep, wk).reshape(B, S, D)
+            y = jax.vmap(combine)(out_flat, slot, keep, wk).reshape(B, S, D)
 
     # load-balance aux loss (Switch-style); top-1 counts via bincount —
     # never materializes the (G, T, E) one-hot buffer
-    me = jnp.mean(probs, axis=(0, 1))                       # (E,)
-    cnt = jnp.zeros((E,), jnp.float32).at[eidx[..., 0].reshape(-1)].add(1.0)
-    ce = cnt / (G * T)
-    aux = E * jnp.sum(me * ce)
+    with sflog.scope("moe.route"):
+        me = jnp.mean(probs, axis=(0, 1))                   # (E,)
+        cnt = jnp.zeros((E,), jnp.float32).at[
+            eidx[..., 0].reshape(-1)].add(1.0)
+        ce = cnt / (G * T)
+        aux = E * jnp.sum(me * ce)
 
     if cfg.moe_shared_ff:
-        shared = (jax.nn.silu(x @ p["shared_gate"]) * (x @ p["shared_in"])) \
-            @ p["shared_out"]
-        y = y + shared
+        with sflog.scope("moe.experts"):
+            shared = (jax.nn.silu(x @ p["shared_gate"])
+                      * (x @ p["shared_in"])) @ p["shared_out"]
+            y = y + shared
     return y, aux

@@ -47,6 +47,33 @@ def next_pow2(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
+@jax.jit
+def serve_cache_insert(cache: Dict, cache1: Dict, slot) -> Dict:
+    """Copy a batch-1 prefill cache into row ``slot`` of the engine cache
+    (every per-slot entry: ``k``/``v``, and hymba's SSM state ``h``)."""
+    with sflog.scope("serve.cache_insert"):
+        out = dict(cache)
+        for name in ("k", "v", "h"):
+            if name in cache:
+                out[name] = jax.lax.dynamic_update_index_in_dim(
+                    cache[name], cache1[name][:, 0].astype(cache[name].dtype),
+                    slot, axis=1)
+        return out
+
+
+@jax.jit
+def serve_sample_greedy(logits: jnp.ndarray) -> jnp.ndarray:
+    with sflog.scope("serve.sample"):
+        return jnp.argmax(logits, axis=-1)
+
+
+@partial(jax.jit, static_argnames="temperature")
+def serve_sample(key, logits: jnp.ndarray, *, temperature: float
+                 ) -> jnp.ndarray:
+    with sflog.scope("serve.sample"):
+        return jax.random.categorical(key, logits / temperature, axis=-1)
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -143,19 +170,26 @@ class ServeEngine:
         return min(next_pow2(plen), self.s_max)
 
     def _prefill_fn(self, bucket: int):
-        cfg = self.cfg
+        cfg, s_max = self.cfg, self.s_max
 
         def build():
-            def fn(params, tokens, last_pos):
-                return T.prefill(params, cfg, tokens=tokens,
-                                 s_max=self.s_max, last_pos=last_pos)
-            return jax.jit(fn)
+            def serve_prefill(params, tokens, last_pos):
+                with sflog.scope("serve.prefill"):
+                    return T.prefill(params, cfg, tokens=tokens,
+                                     s_max=s_max, last_pos=last_pos)
+            return jax.jit(serve_prefill)
         return self.programs.get_or_build(("prefill", bucket), build)
 
     def _decode_fn(self):
-        return self.programs.get_or_build(
-            ("decode", self.batch),
-            lambda: jax.jit(partial(self._decode_impl, self.cfg)))
+        cfg = self.cfg
+
+        def build():
+            def serve_decode(params, tokens, cache, positions):
+                with sflog.scope("serve.decode"):
+                    return self._decode_impl(cfg, params, tokens, cache,
+                                             positions)
+            return jax.jit(serve_decode)
+        return self.programs.get_or_build(("decode", self.batch), build)
 
     @staticmethod
     def _decode_impl(cfg, params, tokens, cache, positions):
@@ -170,40 +204,42 @@ class ServeEngine:
         def body(x, layer_in):
             bp, ck, cv = layer_in
             h = rmsnorm(x, bp["ln1"], cfg.norm_eps)
-            H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-            q = (h @ bp["wq"]).reshape(B, 1, H, hd)
-            k = (h @ bp["wk"]).reshape(B, 1, Hkv, hd)
-            v = (h @ bp["wv"]).reshape(B, 1, Hkv, hd)
-            if cfg.qk_norm:
-                q = rmsnorm(q, bp["q_norm"], cfg.norm_eps)
-                k = rmsnorm(k, bp["k_norm"], cfg.norm_eps)
-            # per-row rope + cache write
-            def rope1(u, p_):
-                # u: (H, hd), p_: scalar -> rope at one absolute position
-                return rope(u[None], p_[None], cfg.rope_theta)[0]
-            q = jax.vmap(rope1)(q[:, 0], pos)[:, None]     # (B, 1, H, hd)
-            k = jax.vmap(rope1)(k[:, 0], pos)[:, None]
-            ck = jax.vmap(
-                lambda c, kk, p_: jax.lax.dynamic_update_slice(
-                    c, kk.astype(c.dtype), (p_, 0, 0)))(ck, k[:, 0][:, None],
-                                                        pos)
-            cv = jax.vmap(
-                lambda c, vv, p_: jax.lax.dynamic_update_slice(
-                    c, vv.astype(c.dtype), (p_, 0, 0)))(cv, v[:, 0][:, None],
-                                                        pos)
-            rep = H // Hkv
-            scale = 1.0 / np.sqrt(hd)
-            kf = jnp.repeat(ck.astype(jnp.float32), rep, axis=2)
-            vf = jnp.repeat(cv.astype(jnp.float32), rep, axis=2)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kf) * scale
-            kpos = jnp.arange(ck.shape[1])
-            mask = kpos[None] <= pos[:, None]
-            if cfg.attn_window:
-                mask &= kpos[None] > pos[:, None] - cfg.attn_window
-            s = jnp.where(mask[:, None, None, :], s, -1e30)
-            pr = jax.nn.softmax(s, axis=-1)
-            attn = jnp.einsum("bhqk,bkhd->bqhd", pr, vf).astype(x.dtype)
-            x = x + attn.reshape(B, 1, H * hd) @ bp["wo"]
+            with sflog.scope("model.attn"):
+                H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+                q = (h @ bp["wq"]).reshape(B, 1, H, hd)
+                k = (h @ bp["wk"]).reshape(B, 1, Hkv, hd)
+                v = (h @ bp["wv"]).reshape(B, 1, Hkv, hd)
+                if cfg.qk_norm:
+                    q = rmsnorm(q, bp["q_norm"], cfg.norm_eps)
+                    k = rmsnorm(k, bp["k_norm"], cfg.norm_eps)
+                # per-row rope + cache write
+                def rope1(u, p_):
+                    # u: (H, hd), p_: scalar -> rope at one absolute position
+                    return rope(u[None], p_[None], cfg.rope_theta)[0]
+                q = jax.vmap(rope1)(q[:, 0], pos)[:, None]  # (B, 1, H, hd)
+                k = jax.vmap(rope1)(k[:, 0], pos)[:, None]
+                ck = jax.vmap(
+                    lambda c, kk, p_: jax.lax.dynamic_update_slice(
+                        c, kk.astype(c.dtype), (p_, 0, 0)))(
+                            ck, k[:, 0][:, None], pos)
+                cv = jax.vmap(
+                    lambda c, vv, p_: jax.lax.dynamic_update_slice(
+                        c, vv.astype(c.dtype), (p_, 0, 0)))(
+                            cv, v[:, 0][:, None], pos)
+                rep = H // Hkv
+                scale = 1.0 / np.sqrt(hd)
+                kf = jnp.repeat(ck.astype(jnp.float32), rep, axis=2)
+                vf = jnp.repeat(cv.astype(jnp.float32), rep, axis=2)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                               kf) * scale
+                kpos = jnp.arange(ck.shape[1])
+                mask = kpos[None] <= pos[:, None]
+                if cfg.attn_window:
+                    mask &= kpos[None] > pos[:, None] - cfg.attn_window
+                s = jnp.where(mask[:, None, None, :], s, -1e30)
+                pr = jax.nn.softmax(s, axis=-1)
+                attn = jnp.einsum("bhqk,bkhd->bqhd", pr, vf).astype(x.dtype)
+                x = x + attn.reshape(B, 1, H * hd) @ bp["wo"]
             h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
             if cfg.is_moe:
                 from ..models.moe import moe_layer
@@ -235,20 +271,14 @@ class ServeEngine:
                 bucket = self._bucket(plen)
                 toks = np.zeros((1, bucket), np.int32)
                 toks[0, :plen] = req.tokens
-                t0 = sflog.op_begin() if sflog.enabled() else None
-                logits, cache1 = self._prefill_fn(bucket)(
-                    self.params, jnp.asarray(toks),
-                    jnp.asarray([plen - 1], np.int32))
-                if t0 is not None:
-                    sflog.op_end("ServePrefill", t0, logits,
-                                 tags={"bucket": bucket, "rid": req.rid})
+                with sflog.span("serve.prefill", rid=req.rid, bucket=bucket):
+                    logits, cache1 = self._prefill_fn(bucket)(
+                        self.params, jnp.asarray(toks),
+                        jnp.asarray([plen - 1], np.int32))
                 # copy slot rows into the engine cache
-                for name in ("k", "v"):
-                    self.cache[name] = self.cache[name].at[:, slot].set(
-                        cache1[name][:, 0])
-                if "h" in self.cache:          # hymba SSM state per slot
-                    self.cache["h"] = self.cache["h"].at[:, slot].set(
-                        cache1["h"][:, 0])
+                with sflog.span("serve.cache_insert"):
+                    self.cache = serve_cache_insert(
+                        self.cache, cache1, np.int32(slot))
                 first = int(self._sample(logits)[0])
                 req.out.append(first)
                 self._c_tokens.add(1)
@@ -257,31 +287,32 @@ class ServeEngine:
                 self.active[slot] = req
 
     def _sample(self, logits: jnp.ndarray) -> np.ndarray:
-        if self.greedy:
-            return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        self.key, sub = jax.random.split(self.key)
-        return np.asarray(jax.random.categorical(
-            sub, logits / self.temperature, axis=-1), np.int32)
+        """Next tokens, read back to the host (the step's sync)."""
+        with sflog.span("serve.sample"):
+            if self.greedy:
+                return np.asarray(serve_sample_greedy(logits), np.int32)
+            self.key, sub = jax.random.split(self.key)
+            return np.asarray(serve_sample(sub, logits,
+                                           temperature=self.temperature),
+                              np.int32)
 
     def step(self) -> int:
         """Admit + one decode step for all active slots.  Returns #pending
         (active slots + queued requests)."""
         if self.t_start is None:
             self.t_start = self.clock()
-        self._admit()
+        with sflog.span("serve.admit"):
+            self._admit()
         if not any(r is not None for r in self.active):
             return len(self.queue)
         last = np.zeros(self.batch, np.int32)
         for s, r in enumerate(self.active):
             if r is not None:
                 last[s] = r.out[-1] if r.out else r.tokens[-1]
-        t0 = sflog.op_begin() if sflog.enabled() else None
-        logits, self.cache = self._decode_fn()(
-            self.params, jnp.asarray(last), self.cache,
-            jnp.asarray(self.positions))
-        if t0 is not None:
-            sflog.op_end("ServeDecode", t0, logits,
-                         tags={"batch": self.batch})
+        with sflog.span("serve.decode", step=self.steps):
+            logits, self.cache = self._decode_fn()(
+                self.params, jnp.asarray(last), self.cache,
+                jnp.asarray(self.positions))
         nxt = self._sample(logits)
         self._c_steps.add(1)
         now = self.clock()

@@ -31,7 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["CGResult", "cg", "cg_async", "as_matvec"]
+from ..core import sflog
+
+__all__ = ["CGResult", "cg", "cg_async", "as_matvec", "step_program"]
 
 
 def as_matvec(op) -> Callable:
@@ -59,15 +61,25 @@ def _step(matvec, x, r, p, rz, M=None):
     step returns both rz = <r, z> (for beta) and <r, r> (for the residual
     convergence check)."""
     Ap = matvec(p)
-    alpha = rz / jnp.vdot(p, Ap)
-    x = x + alpha * p
-    r = r - alpha * Ap
+    with sflog.scope("cg.vec"):
+        alpha = rz / jnp.vdot(p, Ap)
+        x = x + alpha * p
+        r = r - alpha * Ap
     z = r if M is None else M(r)
-    rz_new = jnp.vdot(r, z)
-    beta = rz_new / rz
-    p = z + beta * p
-    rr = rz_new if M is None else jnp.vdot(r, r)
+    with sflog.scope("cg.vec"):
+        rz_new = jnp.vdot(r, z)
+        beta = rz_new / rz
+        p = z + beta * p
+        rr = rz_new if M is None else jnp.vdot(r, r)
     return x, r, p, rz_new, rr
+
+
+def step_program(matvec: Callable, M: Optional[Callable] = None) -> Callable:
+    """One CG iteration over ``matvec`` as the jitted program ``cg_step``
+    (a new program: it traces on its first call)."""
+    def cg_step(x, r, p, rz):
+        return _step(matvec, x, r, p, rz, M)
+    return jax.jit(cg_step)
 
 
 def cg(matvec: Callable, b: jnp.ndarray, x0: Optional[jnp.ndarray] = None,
@@ -88,17 +100,25 @@ def cg(matvec: Callable, b: jnp.ndarray, x0: Optional[jnp.ndarray] = None,
     p = z
     rz = jnp.vdot(r, z)
     rr = rz if M is None else jnp.vdot(r, r)
-    bnorm = float(jnp.sqrt(jnp.vdot(b, b)))
-    step = jax.jit(lambda x, r, p, rz: _step(matvec, x, r, p, rz, M))
+    with sflog.span("cg.readback"):
+        bnorm = float(jnp.sqrt(jnp.vdot(b, b)))
+    step = step_program(matvec, M)
     it = 0
-    rnorm = float(jnp.sqrt(rr))
+    with sflog.span("cg.readback"):
+        rnorm = float(jnp.sqrt(rr))
     while it < maxiter:
         # host reads the residual -> device/host sync every iteration,
         # mirroring VecDot + host convergence check in the paper's CG
         if rnorm <= tol * max(bnorm, 1e-30):
             return CGResult(x, it, rnorm, True)
-        x, r, p, rz, rr = step(x, r, p, rz)
-        rnorm = float(jnp.sqrt(rr))   # blocking host readback
+        with sflog.span("cg.iter", k=it):
+            if it == 0:
+                with sflog.span("cg.jit"):    # traces the new step program
+                    x, r, p, rz, rr = step(x, r, p, rz)
+            else:
+                x, r, p, rz, rr = step(x, r, p, rz)
+            with sflog.span("cg.readback"):
+                rnorm = float(jnp.sqrt(rr))   # blocking host readback
         it += 1
     return CGResult(x, it, rnorm, rnorm <= tol * max(bnorm, 1e-30))
 

@@ -23,13 +23,14 @@ PETSc's MatStash used in step 3 of §6.4).
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import SFComm, StarForest, compose_inverse, ragged_offsets
+from ..core import SFComm, StarForest, compose_inverse, ragged_offsets, sflog
 from ..kernels import ops as kops
 from ..meshdist.section import Section, apply_section
 from .csr import LocalCSR, csr_from_coo, csr_transpose, spgemm
@@ -47,15 +48,26 @@ class _EllBlock:
     cols: jnp.ndarray   # (m, K) padded -> n (trailing zero of x)
     n: int
 
-    def apply(self, x: jnp.ndarray, use_kernel: bool = False) -> jnp.ndarray:
+    def apply(self, x: jnp.ndarray, use_kernel: bool = False,
+              scope: str = "mat.diag") -> jnp.ndarray:
         """y = block @ x.  ``x`` may carry trailing RHS-column dims
         ``(n, *unit)``; the contraction broadcasts over them (the Pallas ELL
-        kernel is single-vector, so multi-RHS takes the einsum path)."""
+        kernel is single-vector, so multi-RHS takes the einsum path).  Runs
+        as the named program ``ell_apply`` under device scope ``scope``."""
+        return ell_apply(self.data, self.cols, x, use_kernel=use_kernel,
+                         scope=scope)
+
+
+@partial(jax.jit, static_argnames=("use_kernel", "scope"))
+def ell_apply(data: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray, *,
+              use_kernel: bool, scope: str) -> jnp.ndarray:
+    """ELL ``data``/``cols`` (padded columns read x's appended zero) @ x."""
+    with sflog.scope(scope):
         xz = jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
         if use_kernel and x.ndim == 1:
-            return kops.spmv_ell(self.data, self.cols, xz)
-        return jnp.einsum("nk,nk...->n...", self.data,
-                          jnp.take(xz, self.cols, axis=0))
+            return kops.spmv_ell(data, cols, xz)
+        return jnp.einsum("nk,nk...->n...", data,
+                          jnp.take(xz, cols, axis=0))
 
 
 class ParCSR:
@@ -224,17 +236,22 @@ class ParCSR:
         x = jnp.asarray(x)
         pend = self.comm.bcast_begin(x, "replace")
         y_parts = []
-        for r in range(self.nranks):
-            c0, c1 = int(self.col_offsets[r]), int(self.col_offsets[r + 1])
-            y_parts.append(self._diag_ell[r].apply(x[c0:c1], use_kernel))
-        y = jnp.concatenate(y_parts)
+        with sflog.scope("mat.diag"):
+            for r in range(self.nranks):
+                c0, c1 = int(self.col_offsets[r]), int(self.col_offsets[r + 1])
+                y_parts.append(self._diag_ell[r].apply(x[c0:c1], use_kernel,
+                                                       "mat.diag"))
+            y = jnp.concatenate(y_parts)
         lvec = pend.end(jnp.zeros((self.sf.nleafspace_total,) + x.shape[1:],
                                   x.dtype))
         y2 = []
-        for r in range(self.nranks):
-            l0, l1 = int(self.lvec_offsets[r]), int(self.lvec_offsets[r + 1])
-            y2.append(self._offd_ell[r].apply(lvec[l0:l1], use_kernel))
-        return y + jnp.concatenate(y2)
+        with sflog.scope("mat.offdiag"):
+            for r in range(self.nranks):
+                l0 = int(self.lvec_offsets[r])
+                l1 = int(self.lvec_offsets[r + 1])
+                y2.append(self._offd_ell[r].apply(lvec[l0:l1], use_kernel,
+                                                  "mat.offdiag"))
+            return y + jnp.concatenate(y2)
 
     def spmv_multi(self, X: jnp.ndarray, use_kernel: bool = False
                    ) -> jnp.ndarray:
@@ -251,8 +268,10 @@ class ParCSR:
         y_parts, l_parts = [], []
         for r in range(self.nranks):
             r0, r1 = int(self.row_offsets[r]), int(self.row_offsets[r + 1])
-            y_parts.append(self._diag_t_ell[r].apply(x[r0:r1], use_kernel))
-            l_parts.append(self._offd_t_ell[r].apply(x[r0:r1], use_kernel))
+            y_parts.append(self._diag_t_ell[r].apply(x[r0:r1], use_kernel,
+                                                     "mat.diag"))
+            l_parts.append(self._offd_t_ell[r].apply(x[r0:r1], use_kernel,
+                                                     "mat.offdiag"))
         y = jnp.concatenate(y_parts)
         lvec_parts = []
         for r in range(self.nranks):

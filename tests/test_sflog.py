@@ -3,9 +3,12 @@ exact per-event exchange counts and byte volumes over the paper's consumer
 paths (CG SpMV, DMDA halo, MoE decode dispatch, bucketed DDP), zero-added-
 retrace proofs on the fused ``cg_async`` / decode-step / jitted-DDP paths,
 identical event streams across backends on the shared ``sf_fixtures``
-matrix, and the <2%-of-one-exchange disabled-overhead bound."""
+matrix, host spans in a profiler trace and device scopes in the lowered
+programs, and the <2%-of-one-exchange disabled-overhead bound."""
 
+import glob
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -140,12 +143,23 @@ def test_overlap_efficiency_from_aggregates(logged):
 
 
 def test_timed_and_context_tagging(logged):
-    with sflog.context(rid="r7", step=3):
-        with sflog.timed("Scoped", nbytes=64.0):
-            pass
+    """A span is one execution of its event, its meta the event's tags;
+    entered while jit traces, it counts ``traced`` only."""
+    with sflog.span("Scoped", rid="r7", step=3):
+        pass
     ev = sflog.event("Scoped")
-    assert ev.count == 1 and ev.bytes == 64.0
+    assert ev.count == 1 and ev.traced == 0 and ev.bytes == 0.0
     assert ev.tags["rid"] == {"r7": 1} and ev.tags["step"] == {"3": 1}
+
+    @jax.jit
+    def f(x):
+        with sflog.span("Scoped", rid="r8"):
+            return x + 1
+
+    for _ in range(3):
+        jax.block_until_ready(f(jnp.ones(2)))
+    assert ev.count == 1 and ev.traced == 1
+    assert ev.tags["rid"] == {"r7": 1}
 
 
 def test_log_view_and_dump_json_render(logged):
@@ -343,9 +357,10 @@ def test_cg_async_fused_loop_zero_added_retraces(tridiag, logged, rng):
 
 
 def test_serving_decode_steps_counted_without_retrace(logged):
-    """Decode-step path: every engine step is one ServeDecode event, every
-    admission one ServePrefill, and a second batch of requests compiles
-    zero new programs (the decode program cache miss count stays flat)."""
+    """Decode-step path: every engine step is one ``serve.decode`` event,
+    every admission one ``serve.prefill`` (and one ``serve.cache_insert``),
+    and a second batch of requests compiles zero new programs (the decode
+    program cache miss count stays flat)."""
     from repro.configs import get_config
     from repro.models import transformer as T
     from repro.serving.engine import Request, ServeEngine
@@ -355,15 +370,17 @@ def test_serving_decode_steps_counted_without_retrace(logged):
     eng = ServeEngine(cfg, params, batch=2, s_max=64)
     done = eng.run([Request(i, [1 + i, 2, 3], max_new=4) for i in range(4)])
     assert len(done) == 4
-    assert sflog.event("ServeDecode").count == eng.steps
-    assert sflog.event("ServePrefill").count == 4
+    assert sflog.event("serve.decode").count == eng.steps
+    assert sflog.event("serve.prefill").count == 4
+    assert sflog.event("serve.cache_insert").count == 4
+    assert sflog.event("serve.sample").count == eng.steps + 4
     misses = eng.programs.stats()["misses"]
     done2 = eng.run([Request(10 + i, [5 + i, 2, 3], max_new=4)
                      for i in range(4)])
     assert len(done2) == 4
     assert eng.programs.stats()["misses"] == misses
-    assert sflog.event("ServeDecode").count == eng.steps
-    assert sflog.event("ServePrefill").count == 8
+    assert sflog.event("serve.decode").count == eng.steps
+    assert sflog.event("serve.prefill").count == 8
 
 
 def test_ddp_jitted_train_path_zero_added_retraces(logged, rng):
@@ -500,5 +517,104 @@ def test_disabled_overhead_under_two_percent_of_one_exchange():
         t_hook = (time.perf_counter() - t0) / n
         assert 12 * t_hook < 0.02 * t_ex, \
             f"hook {t_hook * 1e9:.0f}ns vs exchange {t_ex * 1e6:.1f}us"
+        # a span (with meta) per exchange, the profiler not capturing
+        t_span = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(n // 4):
+                with sflog.span("t_sflog.overhead", rid=1):
+                    pass
+            t_span = min(t_span, (time.perf_counter() - t0) / (n // 4))
+        assert t_span < 0.02 * t_ex, \
+            f"span {t_span * 1e9:.0f}ns vs exchange {t_ex * 1e6:.1f}us"
     finally:
         sflog.set_mode(old)
+
+
+# --------------------------------------------------------------------------
+# spans in the profiler's trace, scopes in the lowered programs
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("mode", ["off", "on"])
+def test_span_writes_host_event_with_meta_into_profiler_trace(mode,
+                                                              tmp_path):
+    """A span is a host event of a captured CPU trace, its meta the event's
+    stats, whether or not event logging is on."""
+    from jax.profiler import ProfileData
+    old = sflog.set_mode(mode)
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with sflog.span("t_sflog.span", rid=7, bucket=256):
+                jax.block_until_ready(jnp.ones(4) + 1)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        sflog.set_mode(old)
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    hits = [dict(ev.stats) for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name == "t_sflog.span"]
+    assert hits == [{"rid": 7, "bucket": 256}]
+
+
+def _hlo(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+
+def test_decode_and_prefill_programs_carry_model_scopes():
+    """The engine's decode program names attention and the MoE stages; the
+    prefill program names attention and its own scope."""
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    from repro.serving.engine import ServeEngine
+    cfg = get_config("phi3.5-moe-42b-a6.6b").smoke_config().scaled(
+        dtype="float32", moe_dispatch="sf")
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    eng = ServeEngine(cfg, params, batch=2, s_max=32)
+    tok = jnp.zeros(2, jnp.int32)
+    txt = eng._decode_fn().lower(params, tok, eng.cache,
+                                 jnp.zeros(2, jnp.int32)).as_text(
+                                     debug_info=True)
+    for name in ("serve.decode", "model.attn", "moe.route", "moe.dispatch",
+                 "moe.experts", "moe.combine", "sf.pack"):
+        assert name in txt, name
+    txt = eng._prefill_fn(8).lower(params, jnp.zeros((1, 8), jnp.int32),
+                                   jnp.zeros(1, jnp.int32)).as_text(
+                                       debug_info=True)
+    for name in ("serve.prefill", "model.attn", "moe.dispatch"):
+        assert name in txt, name
+
+
+def test_cg_step_program_carries_operator_scopes(tridiag, rng):
+    """One CG iteration names the local and off-diagonal SpMV, the ghost
+    exchange and the vector ops."""
+    from repro.solvers.cg import step_program
+    v = jnp.asarray(rng.standard_normal(64).astype(np.float32))
+    txt = step_program(tridiag.spmv).lower(v, v, v, jnp.float32(1.0)) \
+        .as_text(debug_info=True)
+    for name in ("jit(cg_step)", "mat.diag", "mat.offdiag", "cg.vec",
+                 "sf.pack", "sf.unpack"):
+        assert name in txt, name
+
+
+def test_sf_bcast_and_reduce_carry_sf_scopes():
+    """On the kernel backend a bcast packs and unpacks; a reduce onto roots
+    with several leaves packs, combines and unpacks."""
+    sf = fig2_sf()
+    comm = SFComm(sf, backend="pallas")
+    roots = jnp.arange(float(sf.nroots_total), dtype=jnp.float32)
+    leaves = jnp.zeros(sf.nleafspace_total, jnp.float32)
+
+    def bcast_reduce(roots, leaves):
+        out = comm.bcast(roots, leaves, "replace")
+        return comm.reduce(out, jnp.zeros_like(roots), "sum")
+
+    txt = _hlo(bcast_reduce, roots, leaves)
+    for name in ("sf.pack", "sf.combine", "sf.unpack", "jit(sf_pack_rows)",
+                 "jit(sf_segment_reduce)", "jit(sf_unpack_rows)"):
+        assert name in txt, name
+    txt = _hlo(lambda r, l: comm.bcast(r, l, "replace"), roots, leaves)
+    assert "sf.pack" in txt and "sf.unpack" in txt
+    assert "sf.combine" not in txt

@@ -64,6 +64,28 @@ def test_autotune_sweeps_once_then_hits(monkeypatch):
     assert st["sweeps"] == 1 and st["hits"] == 1
 
 
+def test_autotune_sweep_ns_sums_sweep_wall_time(monkeypatch):
+    """``sweep_ns`` adds each sweep's wall time; a hit, a default and a pin
+    add nothing."""
+    monkeypatch.setenv("REPRO_SF_AUTOTUNE", "1")
+    cands = _counting_candidates({"a": 0, "b": 0})
+    args = lambda: (jnp.zeros((8,)),)
+    assert tuning.stats()["sweep_ns"] == 0
+    tuning.autotune("k", ("one",), cands, args, default="a", work=1)
+    one = tuning.stats()["sweep_ns"]
+    assert one > 0
+    tuning.autotune("k", ("one",), cands, args, default="a", work=1)
+    assert tuning.stats()["sweep_ns"] == one        # hit: no sweep
+    tuning.autotune("k", ("two",), cands, args, default="a", work=1)
+    assert tuning.stats()["sweep_ns"] > one
+    monkeypatch.setenv("REPRO_SF_AUTOTUNE", "0")
+    before = tuning.stats()["sweep_ns"]
+    tuning.autotune("k", ("three",), cands, args, default="a", work=1)
+    assert tuning.stats()["sweep_ns"] == before     # default: no sweep
+    tuning.clear_cache()
+    assert tuning.stats()["sweep_ns"] == 0
+
+
 def test_autotune_small_work_takes_default(monkeypatch):
     monkeypatch.delenv("REPRO_SF_AUTOTUNE", raising=False)
     counts = {"a": 0, "b": 0}
