@@ -45,7 +45,7 @@ import numpy as np
 
 from .graph import StarForest
 from .mpiops import Op, get_op
-from .ops import PendingComm, SFOps, _apply_unique
+from .ops import PendingComm, SFOps, _apply_unique, unpack_map
 from .plan import GlobalPlan, build_global_plan
 from .unit import check_plan_unit, resolve_unit
 from .distributed import DistSF
@@ -218,6 +218,10 @@ class PallasBackend:
         self._bcast_strided = pat.detect_strided(p.gr) if p.nedges else None
         self._reduce_strided = pat.detect_strided(self._gl_sorted) \
             if p.nedges else None
+        self._unpack_leaf = unpack_map(p.gl, p.nleafspace)
+        # one map for both reduce unpacks: a duplicate-free plan's
+        # ``dst_sorted`` is its ``seg_dst``
+        self._unpack_seg = unpack_map(red.seg_dst, p.nroots)
 
     @property
     def unit(self):
@@ -269,7 +273,7 @@ class PallasBackend:
                   leafdata: jnp.ndarray) -> jnp.ndarray:
         assert pending.kind == "bcast"
         # each leaf has exactly one root -> unique destinations
-        return _apply_unique(jnp.asarray(leafdata), self.plan.gl,
+        return _apply_unique(jnp.asarray(leafdata), self._unpack_leaf,
                              pending.payload, pending.op)
 
     def bcast(self, rootdata, leafdata, op="replace"):
@@ -315,12 +319,12 @@ class PallasBackend:
         if op.name in ("sum", "prod", "max", "min") and usize:
             if red.duplicate_free:
                 # one slot per root: the unpack scatter is the reduction
-                return _apply_unique(rootdata, red.dst_sorted, sv, op)
+                return _apply_unique(rootdata, self._unpack_seg, sv, op)
             seg = self._segment_reduce(sv, op.name)
-            return _apply_unique(rootdata, red.seg_dst, seg, op)
+            return _apply_unique(rootdata, self._unpack_seg, seg, op)
         # logical ops reduce as max/min over the int32 view (as mpiops does)
         seg = op.segment(sv, red.seg_of_slot, red.nseg)
-        return _apply_unique(rootdata, red.seg_dst, seg, op)
+        return _apply_unique(rootdata, self._unpack_seg, seg, op)
 
     def reduce(self, leafdata, rootdata, op="sum"):
         return self.reduce_end(self.reduce_begin(leafdata, op), rootdata)

@@ -16,6 +16,7 @@ it, so the latency-hiding scheduler overlaps them (DESIGN.md §3.2).
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from functools import partial
 from typing import Optional, Tuple
@@ -31,7 +32,7 @@ from .unit import check_plan_unit
 from . import sflog
 
 __all__ = [
-    "SFOps", "PendingComm",
+    "SFOps", "PendingComm", "UnpackMap", "unpack_map",
 ]
 
 
@@ -56,21 +57,96 @@ class PendingComm:
         return out
 
 
-def _apply_unique(target: jnp.ndarray, idx: np.ndarray, vals: jnp.ndarray,
+# Share of the destination rows an index list must cover for the unpack to
+# gather over an inverse map instead of scattering.  On a TPU v5e XLA's
+# scatter writes unique rows of 2 to 8 f32 elements one after another
+# (~90 ns a row) while the gather form costs ~2.4-5.7 ns per destination
+# row, so the gather wins above about 5% coverage.  Rows of one element
+# scatter at 5-12 ns a row and keep the scatter (PERF.md §6 has the chip
+# measurements).
+DENSE_UNPACK_SHARE = 0.05
+
+_COMBINE = {"add": jnp.add, "multiply": jnp.multiply, "max": jnp.maximum,
+            "min": jnp.minimum}
+
+
+@dataclasses.dataclass(frozen=True)
+class UnpackMap:
+    """Setup product for one static unique-destination index list into
+    ``nrows`` destination rows, in one of three forms:
+
+    * ``"identity"``: the list is ``arange(nrows)``; the unpack is
+      elementwise.
+    * ``"gather"``: the list covers at least ``DENSE_UNPACK_SHARE`` of the
+      rows; ``src`` (nrows,) is each row's position in the list (0 where
+      the row is not written) and ``mask`` marks the written rows.
+    * ``"scatter"``: the list is sparse.
+
+    ``idx`` (the list itself, which every form can scatter through),
+    ``src`` and ``mask`` are int32/bool device arrays made once."""
+
+    form: str
+    nrows: int
+    idx: jax.Array
+    src: Optional[jax.Array] = None
+    mask: Optional[jax.Array] = None
+
+
+def unpack_map(idx, nrows: int) -> UnpackMap:
+    """Choose the unpack form of ``idx`` from its count over ``nrows``."""
+    idx = np.asarray(idx, dtype=np.int64).reshape(-1)
+    n = idx.size
+    # device arrays, not tracers, when set-up runs inside a jax trace
+    with jax.ensure_compile_time_eval():
+        dev_idx = jnp.asarray(idx.astype(np.int32))
+        if n == nrows and np.array_equal(idx, np.arange(n)):
+            return UnpackMap("identity", nrows, dev_idx)
+        if n and n >= DENSE_UNPACK_SHARE * nrows:
+            src = np.zeros(nrows, np.int32)
+            src[idx] = np.arange(n, dtype=np.int32)
+            mask = np.zeros(nrows, bool)
+            mask[idx] = True
+            return UnpackMap("gather", nrows, dev_idx, jnp.asarray(src),
+                             jnp.asarray(mask))
+        return UnpackMap("scatter", nrows, dev_idx)
+
+
+def _apply_unique(target: jnp.ndarray, umap: UnpackMap, vals: jnp.ndarray,
                   op: Op) -> jnp.ndarray:
-    """Scatter ``vals`` into ``target`` at unique ``idx`` with reduction op
-    (the SF unpack: one named program, device scope ``sf.unpack``)."""
-    return sf_unpack_rows(target, idx, vals, mode=op.at_update)
+    """Write ``vals`` into ``target`` at the unique rows of ``umap`` with
+    reduction op (the SF unpack: one named program, device scope
+    ``sf.unpack``).  A destination of another length than the map's, and
+    rows of one element in the gather form, take the scatter."""
+    form = umap.form
+    if target.shape[0] != umap.nrows or (
+            form == "gather" and math.prod(target.shape[1:]) == 1):
+        form = "scatter"
+    if form == "scatter":
+        return sf_unpack_rows(target, umap.idx, vals, mode=op.at_update)
+    return sf_unpack_rows(target, umap.src, vals, umap.mask,
+                          mode=op.at_update, form=form)
 
 
-@partial(jax.jit, static_argnames="mode")
-def sf_unpack_rows(target: jnp.ndarray, idx, vals: jnp.ndarray, *,
-                   mode: str) -> jnp.ndarray:
-    """``target.at[idx].<mode>(vals)`` for unique ``idx``."""
+@partial(jax.jit, static_argnames=("mode", "form"))
+def sf_unpack_rows(target: jnp.ndarray, idx, vals: jnp.ndarray, mask=None,
+                   *, mode: str, form: str = "scatter") -> jnp.ndarray:
+    """``target.at[idx].<mode>(vals)`` for unique ``idx``, computed in the
+    :class:`UnpackMap` form ``form`` (in the gather form ``idx`` and
+    ``mask`` are the map's ``src`` and ``mask``).  Counts
+    ``sf.unpack.<form>`` once per built program."""
+    sflog.counter(f"sf.unpack.{form}").add()
     with sflog.scope("sf.unpack"):
-        return getattr(target.at[idx], mode)(vals.astype(target.dtype),
-                                             unique_indices=True,
-                                             indices_are_sorted=False)
+        vals = vals.astype(target.dtype)
+        if form == "scatter":
+            return getattr(target.at[idx], mode)(vals, unique_indices=True,
+                                                 indices_are_sorted=False)
+        if form == "gather":
+            vals = jnp.take(vals, idx, axis=0)
+        new = vals if mode == "set" else _COMBINE[mode](target, vals)
+        if form == "identity":
+            return new
+        return jnp.where(mask.reshape(mask.shape + (1,) * (target.ndim - 1)),
+                         new, target)
 
 
 class SFOps:
@@ -92,6 +168,10 @@ class SFOps:
             self.plan = plan
         else:
             self.plan = build_global_plan(sf, unit=unit)
+        p = self.plan
+        # setup-time unpack maps (PetscSFSetUp analogue)
+        self._unpack_leaf = unpack_map(p.gl, p.nleafspace)
+        self._unpack_seg = unpack_map(p.red_seg_root, p.nroots)
 
     @property
     def unit(self):
@@ -111,10 +191,9 @@ class SFOps:
 
     def bcast_end(self, pending: PendingComm, leafdata: jnp.ndarray) -> jnp.ndarray:
         assert pending.kind == "bcast"
-        p = self.plan
         # each leaf has exactly one root -> unique destinations
-        return _apply_unique(jnp.asarray(leafdata), p.gl, pending.payload,
-                             pending.op)
+        return _apply_unique(jnp.asarray(leafdata), self._unpack_leaf,
+                             pending.payload, pending.op)
 
     def bcast(self, rootdata, leafdata, op="replace"):
         return self.bcast_end(self.bcast_begin(rootdata, op), leafdata)
@@ -152,7 +231,7 @@ class SFOps:
             sorted_vals = jnp.take(vals, p.red_perm, axis=0)
             seg = op.segment(sorted_vals, p.red_seg_of_edge,
                              int(p.red_seg_root.shape[0]))
-        return _apply_unique(rootdata, p.red_seg_root, seg, op)
+        return _apply_unique(rootdata, self._unpack_seg, seg, op)
 
     def reduce(self, leafdata, rootdata, op="sum"):
         return self.reduce_end(self.reduce_begin(leafdata, op), rootdata)

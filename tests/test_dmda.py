@@ -11,16 +11,16 @@ import numpy as np
 import pytest
 
 from repro.core import SFComm, simulate
+from repro.core.mpiops import get_op
 from repro.meshdist.dmda import DMDA, default_proc_grid
 from repro.sparse.parmat import ParCSR
 
 
-def _expected_local(da, g):
-    """Numpy ground truth: per rank, the ghosted local array filled from the
-    global vector by natural-coordinate indexing (NaN/0 where no owner)."""
-    unit = g.shape[1:]
-    out = np.zeros((da.nlocal_total,) + unit, g.dtype)
-    mask = np.zeros(da.nlocal_total, bool)
+def _local_gid(da):
+    """Numpy ground truth: the global row each local row shows, found by
+    natural-coordinate indexing of every rank's ghosted box (periodic wrap;
+    -1 where no owner, or a corner ghost of a star stencil)."""
+    gid = np.full(da.nlocal_total, -1, np.int64)
     for r in range(da.nranks):
         gbox = da.ghosted_box(r)
         grids = np.meshgrid(*[np.arange(a, b) for a, b in gbox],
@@ -40,9 +40,17 @@ def _expected_local(da, g):
         if da.stencil == "star":
             valid &= outside <= 1
         pos = np.flatnonzero(valid)
-        gid = da.natural_to_global(w[pos])
-        out[da.local_offsets[r] + pos] = g[gid]
-        mask[da.local_offsets[r] + pos] = True
+        gid[da.local_offsets[r] + pos] = da.natural_to_global(w[pos])
+    return gid
+
+
+def _expected_local(da, g):
+    """The ghosted local arrays filled from the global vector (0 where no
+    owner), and the mask of filled rows."""
+    gid = _local_gid(da)
+    mask = gid >= 0
+    out = np.zeros((da.nlocal_total,) + g.shape[1:], g.dtype)
+    out[mask] = g[gid[mask]]
     return out, mask
 
 
@@ -179,3 +187,27 @@ def test_spmv_multi_one_fused_exchange(rng, monkeypatch):
             rtol=1e-4, atol=1e-4)
     with pytest.raises(ValueError, match="expects"):
         A.spmv_multi(X[:, 0])
+
+
+@pytest.mark.parametrize("backend", ["global", "pallas"])
+@pytest.mark.parametrize("interior", ["connect", "skip"])
+def test_halo_unpack_bitwise_equals_numpy_scatter(interior, backend, rng):
+    """A 16^3 grid on 8 ranks with a 4-dof unit: DMGlobalToLocal and
+    DMLocalToGlobal (sum, max) equal a plain numpy scatter bit for bit.
+    Values are small integers, so a sum is exact in any order and the check
+    is on where each value lands."""
+    da = DMDA((16, 16, 16), 8, stencil="star", width=1, periodic=False,
+              interior=interior)
+    gid = _local_gid(da)
+    pos = np.flatnonzero(gid >= 0)
+    g = rng.integers(-8, 8, (da.nglobal, 4)).astype(np.float32)
+    want_l = np.zeros((da.nlocal_total, 4), np.float32)
+    want_l[pos] = g[gid[pos]]
+    got_l = np.asarray(da.global_to_local(g, backend=backend))
+    assert got_l.tobytes() == want_l.tobytes()
+    lv = rng.integers(-8, 8, (da.nlocal_total, 4)).astype(np.float32)
+    for op, ufunc in (("sum", np.add), ("max", np.maximum)):
+        want = np.full((da.nglobal, 4), get_op(op).identity_of(np.float32))
+        ufunc.at(want, gid[pos], lv[pos])
+        got = np.asarray(da.local_to_global(lv, op=op, backend=backend))
+        assert got.tobytes() == want.tobytes(), op

@@ -104,3 +104,78 @@ def test_errors():
     sf2.set_graph(1, 1, None, [])
     with pytest.raises(ValueError):
         sf2.setup()  # root offset beyond owner nroots
+
+
+# ------------------------------------------------------------ unpack forms
+_ROWS = 64
+
+
+def _index_list(kind, rng):
+    """A unique-destination index list into ``_ROWS`` rows: every row in
+    order, all but three rows shuffled, or too few rows for a gather."""
+    from repro.core.ops import DENSE_UNPACK_SHARE
+    if kind == "identity":
+        return np.arange(_ROWS)
+    if kind == "dense":
+        return rng.permutation(np.setdiff1d(np.arange(_ROWS), [0, 9, 63]))
+    need = int(np.ceil(DENSE_UNPACK_SHARE * _ROWS))
+    size = need if kind == "threshold" else need - 1
+    return rng.choice(_ROWS, size=size, replace=False)
+
+
+def _rows(rng, shape, dtype):
+    if dtype == "int32":
+        return jnp.asarray(rng.integers(-50, 50, shape), jnp.int32)
+    return jnp.asarray(rng.standard_normal(shape), dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int32"])
+@pytest.mark.parametrize("unit", [(), (4,), (2, 3)])
+@pytest.mark.parametrize("op", ["replace", "sum", "max", "min", "prod"])
+@pytest.mark.parametrize("kind", ["identity", "dense", "sparse"])
+def test_unpack_bitwise_equals_scatter(kind, op, unit, dtype, rng):
+    """Each unpack form writes exactly what the unique-index scatter
+    writes, bit for bit."""
+    from repro.core.mpiops import get_op
+    from repro.core.ops import _apply_unique, unpack_map
+    idx = _index_list(kind, rng)
+    target = _rows(rng, (_ROWS,) + unit, dtype)
+    vals = _rows(rng, (idx.size,) + unit, dtype)
+    o = get_op(op)
+    want = getattr(target.at[idx], o.at_update)(vals, unique_indices=True)
+    got = _apply_unique(target, unpack_map(idx, _ROWS), vals, o)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+_MAP_FORM = {"identity": "identity", "dense": "gather", "threshold": "gather",
+             "sparse": "scatter"}
+
+
+@pytest.mark.parametrize("kind,rows,unit,form", [
+    ("identity", _ROWS, (2,), "identity"), ("dense", _ROWS, (2,), "gather"),
+    ("threshold", _ROWS, (2,), "gather"), ("sparse", _ROWS, (2,), "scatter"),
+    ("dense", _ROWS + 5, (2,), "scatter"), ("dense", _ROWS, (), "scatter"),
+    ("identity", _ROWS, (), "identity")])
+def test_unpack_form_follows_density(kind, rows, unit, form, rng):
+    """The map's form follows the index count over the destination rows,
+    and each built unpack program counts the form it ran once.  A
+    destination of another length than the map's, and one-element rows in
+    the gather form, take the scatter."""
+    from repro.core import sflog
+    from repro.core.mpiops import get_op
+    from repro.core.ops import _apply_unique, sf_unpack_rows, unpack_map
+    idx = _index_list(kind, rng)
+    umap = unpack_map(idx, _ROWS)
+    assert umap.form == _MAP_FORM[kind]
+    sf_unpack_rows.clear_cache()
+    before = sflog.counters()
+    target = jnp.zeros((rows,) + unit, jnp.float32)
+    vals = jnp.ones((idx.size,) + unit, jnp.float32)
+    want = target.at[idx].add(vals)
+    for _ in range(2):
+        got = _apply_unique(target, umap, vals, get_op("sum"))
+    moved = {k: v - before.get(k, 0) for k, v in sflog.counters().items()
+             if k.startswith("sf.unpack.") and v != before.get(k, 0)}
+    assert moved == {f"sf.unpack.{form}": 1}
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
