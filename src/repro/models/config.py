@@ -31,6 +31,24 @@ class ModelConfig:
     moe_capacity: float = 1.25
     moe_shared_ff: int = 0       # shared-expert hidden dim (0 = none)
     moe_dispatch: str = "sf"     # sf (star-forest routed) | dense
+    moe_score: str = "softmax"   # softmax | sigmoid (DeepSeek-V3 scoring)
+    moe_score_bias: bool = False  # top-k on score + correction bias (noaux_tc)
+    moe_route_scale: float = 1.0  # routed_scaling_factor on the top-k weights
+    # experts held here: 0 = every expert in capacity slots (moe_layer's
+    # capacity path); n > 0 = experts [moe_held_offset, +n) of moe_experts in
+    # the grouped, dropless layer (expert parallelism's share of one chip)
+    moe_held: int = 0
+    moe_held_offset: int = 0
+    dense_layers: int = 0        # leading dense SwiGLU layers of width d_ff
+    # latent attention (MLA, DeepSeek-V2/V3); kv_lora_rank > 0 selects it
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (factor 0 = none): factor, original context, betas,
+    # mscale and mscale_all_dim as in the published rope_scaling
+    yarn: Tuple[float, ...] = ()
     # hybrid / ssm
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -57,15 +75,32 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.moe_experts > 0
 
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def _attn_params(self) -> int:
+        D, H, Hkv, hd = self.d_model, self.n_heads, self.n_kv_heads, self.hd
+        if self.is_mla:
+            qr, kr = self.q_lora_rank, self.kv_lora_rank
+            dq = self.qk_nope_dim + self.qk_rope_dim
+            return (D * qr + qr + qr * H * dq + D * (kr + self.qk_rope_dim)
+                    + kr + kr * H * (self.qk_nope_dim + self.v_head_dim)
+                    + H * self.v_head_dim * D)
+        attn = D * (H * hd) + 2 * D * (Hkv * hd) + (H * hd) * D
+        if self.qk_norm:
+            attn += 2 * hd
+        return attn
+
     def param_count(self) -> int:
         """Total parameters N (embedding included once)."""
         D, L, V = self.d_model, self.n_layers, self.vocab
         H, Hkv, hd = self.n_heads, self.n_kv_heads, self.hd
-        attn = D * (H * hd) + 2 * D * (Hkv * hd) + (H * hd) * D
-        if self.qk_norm:
-            attn += 2 * hd
+        attn = self._attn_params()
         if self.is_moe:
             ff = self.moe_experts * (3 * D * self.moe_dff) + D * self.moe_experts
+            if self.moe_score_bias:
+                ff += self.moe_experts
             if self.moe_shared_ff:
                 ff += 3 * D * self.moe_shared_ff
         elif self.d_ff:
@@ -89,13 +124,15 @@ class ModelConfig:
                                  * D * self.d_ff + norms)
         cross = L * (D * (H * hd) + 2 * D * (Hkv * hd) + (H * hd) * D + D) \
             if self.cross_attention else 0
-        return L * per_layer + emb + head + enc + cross + 2 * D
+        # leading dense layers swap their FFN for a SwiGLU of width d_ff
+        lead = self.dense_layers * (3 * D * self.d_ff - ff)
+        return L * per_layer + lead + emb + head + enc + cross + 2 * D
 
     def active_param_count(self) -> int:
         """Active parameters per token (MoE: top-k experts only)."""
         if not self.is_moe:
             return self.param_count()
-        D, L = self.d_model, self.n_layers
+        D, L = self.d_model, self.n_layers - self.dense_layers
         dense = self.param_count() - L * (
             self.moe_experts * 3 * D * self.moe_dff)
         act_ff = L * self.moe_topk * 3 * D * self.moe_dff
@@ -122,5 +159,12 @@ class ModelConfig:
             ssm_state=8 if self.ssm_state else 0,
             enc_layers=2 if self.enc_layers else 0,
             attn_window=16 if self.attn_window else None,
+            moe_held=4 if self.moe_held else 0,
+            moe_held_offset=0,
+            q_lora_rank=32 if self.q_lora_rank else 0,
+            kv_lora_rank=16 if self.kv_lora_rank else 0,
+            qk_nope_dim=16 if self.qk_nope_dim else 0,
+            qk_rope_dim=8 if self.qk_rope_dim else 0,
+            v_head_dim=16 if self.v_head_dim else 0,
             name=self.name + "-smoke",
         )

@@ -19,7 +19,8 @@ from .config import ModelConfig
 from .sharding import constrain
 from ..core import sflog
 
-__all__ = ["rmsnorm", "rope", "attention", "attention_decode", "mlp",
+__all__ = ["rmsnorm", "rope", "attention", "attention_decode",
+           "attention_decode_slots", "mlp",
            "init_attn", "init_mlp", "cross_attention"]
 
 
@@ -69,20 +70,23 @@ def init_attn(key, cfg: ModelConfig, layers: int) -> Dict:
 
 
 def _chunked_attn(q, k, v, qpos0: int, causal: bool, window, chunk: int,
-                  chunk_q: int = 512):
+                  chunk_q: int = 512, scale: Optional[float] = None):
     """Flash-style attention as a checkpointed nested scan — the
     differentiable training/prefill counterpart of the Pallas flash kernel.
 
     Outer scan over Q chunks (each body under ``jax.checkpoint``: backward
     stores only per-q-chunk outputs, never the (Sq × Skv) logits); inner
     online-softmax scan over KV chunks.  q: (B, Sq, H, hd); k/v:
-    (B, Skv, Hkv, hd); ``qpos0``: absolute position of q[0] (= Skv - Sq for
-    suffix queries).
+    (B, Skv, Hkv, hd); v may have another head dim than q and k (MLA);
+    ``qpos0``: absolute position of q[0] (= Skv - Sq for suffix queries);
+    ``scale`` defaults to 1/sqrt(hd).
     """
     B, Sq, H, hd = q.shape
     _, Skv, Hkv, _ = k.shape
+    hdv = v.shape[-1]
     rep = H // Hkv
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
 
     ck = min(chunk, Skv)
     nk = (Skv + ck - 1) // ck
@@ -90,7 +94,7 @@ def _chunked_attn(q, k, v, qpos0: int, causal: bool, window, chunk: int,
         k = jnp.pad(k, ((0, 0), (0, nk * ck - Skv), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, nk * ck - Skv), (0, 0), (0, 0)))
     kc = k.reshape(B, nk, ck, Hkv, hd).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(B, nk, ck, Hkv, hd).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(B, nk, ck, Hkv, hdv).transpose(1, 0, 2, 3, 4)
     kv_off = jnp.arange(nk) * ck
 
     cq = min(chunk_q, Sq)
@@ -130,16 +134,16 @@ def _chunked_attn(q, k, v, qpos0: int, causal: bool, window, chunk: int,
 
         m0 = jnp.full((B, Hkv, rep, cq), -1e30, jnp.float32)
         l0 = jnp.zeros((B, Hkv, rep, cq), jnp.float32)
-        a0 = jnp.zeros((B, Hkv, rep, cq, hd), jnp.float32)
+        a0 = jnp.zeros((B, Hkv, rep, cq, hdv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(kv_body, (m0, l0, a0),
                                       (kc, vc, kv_off))
         l = jnp.where(l == 0.0, 1.0, l)
         out_g = (acc / l[..., None]).astype(q.dtype)     # (B,Hkv,rep,cq,hd)
-        return None, out_g.reshape(B, Hkv * rep, cq, hd)
+        return None, out_g.reshape(B, Hkv * rep, cq, hdv)
 
     _, outs = jax.lax.scan(q_chunk_body, None, (qc, q_off))
-    # outs: (nq, B, H, cq, hd) -> (B, Sq, H, hd)
-    out = outs.transpose(1, 0, 3, 2, 4).reshape(B, nq * cq, H, hd)
+    # outs: (nq, B, H, cq, hdv) -> (B, Sq, H, hdv)
+    out = outs.transpose(1, 0, 3, 2, 4).reshape(B, nq * cq, H, hdv)
     return out[:, :Sq]
 
 
@@ -227,6 +231,51 @@ def attention_decode(x: jnp.ndarray, p: Dict, cfg: ModelConfig, cache_k,
         out = jnp.einsum("bkrs,bskd->bkrd", pr.astype(cache_v.dtype), cache_v,
                          preferred_element_type=jnp.float32).astype(x.dtype)
         return out.reshape(B, 1, H * hd) @ p["wo"], cache_k, cache_v
+
+
+def attention_decode_slots(x: jnp.ndarray, p: Dict, cfg: ModelConfig,
+                           ck, cv, pos: jnp.ndarray):
+    """Single-token decode in which each batch row has its own position:
+    x (B, 1, D); ck/cv (B, Smax, Hkv, hd); pos (B,).  The serving engine's
+    slot form (K/V repeated to the query heads in f32).  Returns
+    (out (B, 1, D), ck', cv')."""
+    with sflog.scope("model.attn"):
+        B = x.shape[0]
+        H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+        q = (x @ p["wq"]).reshape(B, 1, H, hd)
+        k = (x @ p["wk"]).reshape(B, 1, Hkv, hd)
+        v = (x @ p["wv"]).reshape(B, 1, Hkv, hd)
+        if cfg.qk_norm:
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+            k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
+        # per-row rope + cache write
+        def rope1(u, p_):
+            # u: (H, hd), p_: scalar -> rope at one absolute position
+            return rope(u[None], p_[None], cfg.rope_theta)[0]
+        q = jax.vmap(rope1)(q[:, 0], pos)[:, None]  # (B, 1, H, hd)
+        k = jax.vmap(rope1)(k[:, 0], pos)[:, None]
+        ck = jax.vmap(
+            lambda c, kk, p_: jax.lax.dynamic_update_slice(
+                c, kk.astype(c.dtype), (p_, 0, 0)))(
+                    ck, k[:, 0][:, None], pos)
+        cv = jax.vmap(
+            lambda c, vv, p_: jax.lax.dynamic_update_slice(
+                c, vv.astype(c.dtype), (p_, 0, 0)))(
+                    cv, v[:, 0][:, None], pos)
+        rep = H // Hkv
+        scale = 1.0 / np.sqrt(hd)
+        kf = jnp.repeat(ck.astype(jnp.float32), rep, axis=2)
+        vf = jnp.repeat(cv.astype(jnp.float32), rep, axis=2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                       kf) * scale
+        kpos = jnp.arange(ck.shape[1])
+        mask = kpos[None] <= pos[:, None]
+        if cfg.attn_window:
+            mask &= kpos[None] > pos[:, None] - cfg.attn_window
+        s = jnp.where(mask[:, None, None, :], s, -1e30)
+        pr = jax.nn.softmax(s, axis=-1)
+        attn = jnp.einsum("bhqk,bkhd->bqhd", pr, vf).astype(x.dtype)
+        return attn.reshape(B, 1, H * hd) @ p["wo"], ck, cv
 
 
 # --------------------------------------------------------------------------

@@ -24,6 +24,22 @@ shapes, G = 1 for tiny decode batches (auto).
 Expert weights are stacked (E, D, F) and sharded over the model axis (EP)
 and the data axis (FSDP); the expert compute is a single einsum over the
 sharded buffer, which is what the MXU wants.
+
+**Held experts** (``cfg.moe_held`` = n > 0, expert parallelism's share of
+one chip): the layer holds experts ``[moe_held_offset, +n)`` of the
+``moe_experts`` the router scores.  The router keeps its full width and
+top-k; only picks to held experts make rows, sorted by expert, and every
+other pick is left out (its part of the result lies on another chip).  The
+SF plan has the tokens as roots and the held picks as leaves: dispatch is
+a root->leaf ``bcast`` of each pick's token row, the experts run as one
+grouped product (``lax.ragged_dot``) over rows sorted by expert, and
+combine is the transpose leaf->root ``reduce`` (sum) of the weighted
+expert rows onto their tokens.  Rows come in chunks of a static bound
+(``moe_capacity`` times the expected held picks); a routing that sends
+more picks here runs further chunks, so the layer is dropless for any
+routing.  Scoring may be ``softmax`` or DeepSeek-V3's ``sigmoid`` with a
+correction bias added for the choice only (``moe_score_bias``), the top-k
+weights normalised and scaled by ``moe_route_scale``.
 """
 
 from __future__ import annotations
@@ -39,7 +55,7 @@ from ..core import sflog
 from ..core.dynplan import DynPlan, PlanCache
 from ..core.fields import FieldBundle
 
-__all__ = ["init_moe", "moe_layer", "plan_cache"]
+__all__ = ["init_moe", "moe_layer", "plan_cache", "grouped_rows"]
 
 # module-level skeleton cache: one DynPlan per dispatch signature, shared by
 # every layer/step with the same (G, T, k, E, C, D, dtype) problem.  The
@@ -61,16 +77,20 @@ def plan_cache() -> PlanCache:
 
 def init_moe(key, cfg: ModelConfig, layers: int) -> Dict:
     D, E, F = cfg.d_model, cfg.moe_experts, cfg.moe_dff
+    Eh = cfg.moe_held or E                 # expert weights held here
     dt = jnp.dtype(cfg.dtype)
-    ks = jax.random.split(key, 5)
+    ks = jax.random.split(key, 6)
     s = 1.0 / np.sqrt(D)
     so = 1.0 / np.sqrt(F) / np.sqrt(2 * cfg.n_layers)
     p = {
         "router": (jax.random.normal(ks[0], (layers, D, E)) * s).astype(jnp.float32),
-        "w_in": (jax.random.normal(ks[1], (layers, E, D, F)) * s).astype(dt),
-        "w_gate": (jax.random.normal(ks[2], (layers, E, D, F)) * s).astype(dt),
-        "w_out": (jax.random.normal(ks[3], (layers, E, F, D)) * so).astype(dt),
+        "w_in": (jax.random.normal(ks[1], (layers, Eh, D, F)) * s).astype(dt),
+        "w_gate": (jax.random.normal(ks[2], (layers, Eh, D, F)) * s).astype(dt),
+        "w_out": (jax.random.normal(ks[3], (layers, Eh, F, D)) * so).astype(dt),
     }
+    if cfg.moe_score_bias:
+        p["router_bias"] = jax.random.uniform(ks[5], (layers, E),
+                                              jnp.float32, 0.0, 0.01)
     if cfg.moe_shared_ff:
         Fs = cfg.moe_shared_ff
         k1, k2, k3 = jax.random.split(ks[4], 3)
@@ -139,9 +159,112 @@ def _moe_plan(G: int, T: int, k: int, E: int, C: int, D: int,
         sig, lambda: DynPlan(G * E * C, G * T * k, label=("moe",) + sig))
 
 
+def _route(logits, p: Dict, cfg: ModelConfig):
+    """Router scores (T.., E), the top-k weights (f32) and expert ids."""
+    k = cfg.moe_topk
+    if cfg.moe_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + p["router_bias"] if cfg.moe_score_bias else scores
+        _, eidx = jax.lax.top_k(choice, k)
+        wk = jnp.take_along_axis(scores, eidx, axis=-1)
+        wk = wk / (jnp.sum(wk, axis=-1, keepdims=True) + 1e-20)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        wk, eidx = jax.lax.top_k(scores, k)
+        wk = wk / jnp.sum(wk, axis=-1, keepdims=True)
+    if cfg.moe_route_scale != 1.0:
+        wk = wk * cfg.moe_route_scale
+    return scores, wk, eidx
+
+
+def _aux_loss(scores, eidx, cfg: ModelConfig):
+    """Switch-style load-balance loss over the router's probabilities (the
+    sigmoid scores normalised); top-1 counts via bincount, never the
+    (.., E) one-hot buffer."""
+    E = cfg.moe_experts
+    if cfg.moe_score == "sigmoid":
+        scores = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    me = jnp.mean(scores.reshape(-1, E), axis=0)
+    cnt = jnp.zeros((E,), jnp.float32).at[eidx[..., 0].reshape(-1)].add(1.0)
+    return E * jnp.sum(me * (cnt / eidx[..., 0].size))
+
+
+def grouped_rows(T: int, cfg: ModelConfig) -> Tuple[int, int]:
+    """(rows per chunk, chunks) of the held-expert layer for T tokens: the
+    chunk is ``moe_capacity`` times the expected held picks under uniform
+    routing (T k n / E), a multiple of 8; the chunks cover the most picks
+    that can land here, T min(k, n)."""
+    E, k, n = cfg.moe_experts, cfg.moe_topk, cfg.moe_held
+    most = T * min(k, n)
+    want = int(np.ceil(cfg.moe_capacity * T * k * n / E))
+    rows = min(most, -(-want // 8) * 8)
+    return rows, -(-most // rows)
+
+
+def _moe_held(x, p: Dict, cfg: ModelConfig, valid):
+    """The held-expert layer (module docstring): x (B, S, D) -> (y, aux)."""
+    B, S, D = x.shape
+    T, E, k, n = B * S, cfg.moe_experts, cfg.moe_topk, cfg.moe_held
+    sflog.counter("moe.held_experts").value = n
+    rows, chunks = grouped_rows(T, cfg)
+    N = T * k
+    with sflog.scope("moe.route"):
+        xt = x.reshape(T, D)
+        logits = xt.astype(jnp.float32) @ p["router"]
+        scores, wk, eidx = _route(logits, p, cfg)
+        local = eidx - cfg.moe_held_offset
+        held = (local >= 0) & (local < n)
+        if valid is not None:
+            held = held & valid.reshape(T, 1)
+        key = jnp.where(held, local, n).reshape(-1)
+        # held picks first, grouped by expert; the rest never make a row
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        counts = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
+        first = jnp.cumsum(counts) - counts
+        nheld = jnp.sum(counts)
+        pad = chunks * rows - N
+        tok = jnp.pad(order // k, (0, max(pad, 0)))[:chunks * rows]
+        wrow = jnp.pad(wk.reshape(-1)[order], (0, max(pad, 0)))[
+            :chunks * rows]
+        plan = _PLANS.get_or_build(
+            ("held", T, rows, D, jnp.dtype(x.dtype).str),
+            lambda: DynPlan(T, rows, label=("moe-held", T, rows, D)))
+
+    def chunk(c, y):
+        lo = c * rows
+        live = lo + jnp.arange(rows) < nheld
+        # leaf r -> root (token) of the r-th held pick; past the held picks
+        # the drop row
+        leaf_root = jnp.where(live, jax.lax.dynamic_slice(tok, (lo,),
+                                                          (rows,)), T)
+        sizes = jnp.clip(jnp.minimum(first + counts, lo + rows)
+                         - jnp.maximum(first, lo), 0).astype(jnp.int32)
+        with sflog.scope("moe.dispatch"):
+            h = plan.bcast(xt, leaf_root)
+        with sflog.scope("moe.experts"):
+            up = jax.lax.ragged_dot(h, p["w_in"], sizes)
+            gate = jax.lax.ragged_dot(h, p["w_gate"], sizes)
+            out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p["w_out"],
+                                     sizes)
+        with sflog.scope("moe.combine"):
+            w = jax.lax.dynamic_slice(wrow, (lo,), (rows,))
+            return y + plan.reduce(out.astype(jnp.float32) * w[:, None],
+                                   leaf_root, op="sum")
+
+    y = chunk(0, jnp.zeros((T, D), jnp.float32))
+    if chunks > 1:
+        y = jax.lax.fori_loop(
+            1, chunks, lambda c, y: jax.lax.cond(
+                c * rows < nheld, chunk, lambda c, y: y, c, y), y)
+    with sflog.scope("moe.route"):
+        aux = _aux_loss(scores, eidx, cfg)
+    return y.astype(x.dtype).reshape(B, S, D), aux
+
+
 def moe_layer(x: jnp.ndarray, p: Dict, cfg: ModelConfig, *,
               groups: Optional[int] = None,
-              dispatch: Optional[str] = None
+              dispatch: Optional[str] = None,
+              valid: Optional[jnp.ndarray] = None
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: (B, S, D) -> (y, aux_loss).  Router in fp32; top-k softmax over the
     selected logits; capacity C = ceil(S_g * k * cf / E) per group.
@@ -153,7 +276,14 @@ def moe_layer(x: jnp.ndarray, p: Dict, cfg: ModelConfig, *,
     ``cfg.moe_dispatch``): dispatch = fused leaf→root reduce of the hidden
     state + combine weight, combine = root→leaf bcast of the weighted
     expert outputs.  ``dispatch="dense"`` keeps the legacy per-group
-    scatter/gather formulation (same slots, same drops, same weights)."""
+    scatter/gather formulation (same slots, same drops, same weights).
+
+    With ``cfg.moe_held`` the held-expert layer runs instead (module
+    docstring); ``valid`` (B, S) then marks the tokens that route (a
+    bucketed prefill's pad tail makes no rows)."""
+    if cfg.moe_held:
+        y, aux = _moe_held(x, p, cfg, valid)
+        return _shared(x, y, p, cfg), aux
     from .sharding import constrain
     mode = dispatch if dispatch is not None \
         else getattr(cfg, "moe_dispatch", "sf")
@@ -169,9 +299,8 @@ def moe_layer(x: jnp.ndarray, p: Dict, cfg: ModelConfig, *,
         xg = constrain(x.reshape(G, T, D))
         logits = constrain(jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
                                       p["router"]))
-        probs = jax.nn.softmax(logits, axis=-1)
-        wk, eidx = jax.lax.top_k(probs, k)              # (G, T, k)
-        wk = (wk / jnp.sum(wk, axis=-1, keepdims=True)).astype(x.dtype)
+        probs, wk, eidx = _route(logits, p, cfg)        # (G, T, k)
+        wk = wk.astype(x.dtype)
         slot, keep = jax.vmap(lambda e1: _capacity_slots(e1, C, E))(eidx)
         if mode == "sf":
             plan = _moe_plan(G, T, k, E, C, D, x.dtype)
@@ -237,18 +366,15 @@ def moe_layer(x: jnp.ndarray, p: Dict, cfg: ModelConfig, *,
 
             y = jax.vmap(combine)(out_flat, slot, keep, wk).reshape(B, S, D)
 
-    # load-balance aux loss (Switch-style); top-1 counts via bincount —
-    # never materializes the (G, T, E) one-hot buffer
     with sflog.scope("moe.route"):
-        me = jnp.mean(probs, axis=(0, 1))                   # (E,)
-        cnt = jnp.zeros((E,), jnp.float32).at[
-            eidx[..., 0].reshape(-1)].add(1.0)
-        ce = cnt / (G * T)
-        aux = E * jnp.sum(me * ce)
+        aux = _aux_loss(probs, eidx, cfg)
+    return _shared(x, y, p, cfg), aux
 
-    if cfg.moe_shared_ff:
-        with sflog.scope("moe.experts"):
-            shared = (jax.nn.silu(x @ p["shared_gate"])
-                      * (x @ p["shared_in"])) @ p["shared_out"]
-            y = y + shared
-    return y, aux
+
+def _shared(x, y, p: Dict, cfg: ModelConfig):
+    """Add the shared expert (every token, on every chip) to ``y``."""
+    if not cfg.moe_shared_ff:
+        return y
+    with sflog.scope("moe.experts"), sflog.scope("moe.shared"):
+        return y + (jax.nn.silu(x @ p["shared_gate"])
+                    * (x @ p["shared_in"])) @ p["shared_out"]

@@ -33,15 +33,17 @@ import numpy as np
 
 from .config import ModelConfig
 from .sharding import constrain
-from .layers import (attention, attention_decode, cross_attention, init_attn,
-                     init_mlp, mlp, rmsnorm)
+from .layers import (attention, attention_decode, attention_decode_slots,
+                     cross_attention, init_attn, init_mlp, mlp, rmsnorm)
+from .mla import init_mla, mla_attention, mla_decode
 from .moe import init_moe, moe_layer
+from ..core import sflog
 from .ssm import init_ssm, ssm_scan, ssm_step
 from .xlstm import (init_xlstm_pair, init_xlstm_state, xlstm_pair_scan,
                     xlstm_pair_step)
 
 __all__ = ["init_params", "forward", "prefill", "decode_step",
-           "hymba_windows", "init_cache"]
+           "decode_slots", "hymba_windows", "init_cache"]
 
 
 # --------------------------------------------------------------------------
@@ -73,15 +75,20 @@ def init_params(key, cfg: ModelConfig) -> Dict:
         params["pairs"] = init_xlstm_pair(keys[2], cfg, L // 2)
         return params
 
+    Ld = cfg.dense_layers
     blocks: Dict = {
-        "ln1": jnp.ones((L, D), dt),
-        "ln2": jnp.ones((L, D), dt),
-        **init_attn(keys[3], cfg, L),
+        "ln1": jnp.ones((L - Ld, D), dt),
+        "ln2": jnp.ones((L - Ld, D), dt),
+        **_init_attn(keys[3], cfg, L - Ld),
     }
     if cfg.is_moe:
-        blocks.update(init_moe(keys[4], cfg, L))
+        blocks.update(init_moe(keys[4], cfg, L - Ld))
     elif cfg.d_ff:
-        blocks.update(init_mlp(keys[5], cfg, L))
+        blocks.update(init_mlp(keys[5], cfg, L - Ld))
+    if Ld:
+        params["dense_blocks"] = {
+            "ln1": jnp.ones((Ld, D), dt), "ln2": jnp.ones((Ld, D), dt),
+            **_init_attn(keys[10], cfg, Ld), **init_mlp(keys[11], cfg, Ld)}
     if cfg.block_kind == "hymba":
         blocks.update(init_ssm(keys[6], cfg, L))
         blocks["ln_ssm_out"] = jnp.ones((L, D), dt)
@@ -106,11 +113,64 @@ def init_params(key, cfg: ModelConfig) -> Dict:
     return params
 
 
+def _init_attn(key, cfg: ModelConfig, layers: int) -> Dict:
+    return init_mla(key, cfg, layers) if cfg.is_mla else \
+        init_attn(key, cfg, layers)
+
+
+# --------------------------------------------------------------------------
+# layer stacks
+# --------------------------------------------------------------------------
+def _stacks(params, cfg: ModelConfig):
+    """(cache-key prefix, stacked block params, FFN kind, layer slice) of
+    each scanned stack in order: the leading dense layers (``lead``), then
+    the main stack (``moe``, ``mlp`` or ``None``)."""
+    Ld, L = cfg.dense_layers, cfg.n_layers
+    main = "moe" if cfg.is_moe else ("mlp" if cfg.d_ff else None)
+    out = [("dense_", params["dense_blocks"], "lead", slice(0, Ld))] \
+        if Ld else []
+    return out + [("", params["blocks"], main, slice(Ld, L))]
+
+
+def _cache_names(cfg: ModelConfig, prefix: str):
+    """The two per-layer attention cache entries of a stack."""
+    a, b = ("ckv", "kpe") if cfg.is_mla else ("k", "v")
+    return prefix + a, prefix + b
+
+
+def _attend(h, bp, cfg: ModelConfig, window, kernel: bool = False,
+            lengths=None):
+    """Full-sequence attention -> (output, the two cache entries);
+    ``kernel``: forward-only kernels allowed (serving's prefill), which
+    may skip the rows past each sequence's ``lengths``."""
+    if cfg.is_mla:
+        out, lat = mla_attention(h, bp, cfg, kernel=kernel, lengths=lengths)
+        return out, (lat["ckv"], lat["kpe"])
+    return attention(h, bp, cfg, window=window)
+
+
+def _ffn(x, bp, cfg: ModelConfig, kind, valid=None):
+    """Pre-norm FFN residual of one layer -> (x, aux)."""
+    aux = jnp.zeros((), jnp.float32)
+    if kind is None:
+        return x, aux
+    h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
+    if kind == "moe":
+        ff, aux = moe_layer(h2, bp, cfg, valid=valid)
+    elif kind == "lead":
+        with sflog.scope("model.dense_mlp"):
+            ff = mlp(h2, bp, cfg)
+    else:
+        ff = mlp(h2, bp, cfg)
+    return x + ff, aux
+
+
 # --------------------------------------------------------------------------
 # block bodies
 # --------------------------------------------------------------------------
-def _block_train(x, bp, cfg: ModelConfig, window, enc_kv=None, cross_bp=None):
-    """One decoder block, full sequence.  Returns (x, aux, (k, v)).
+def _block_train(x, bp, cfg: ModelConfig, window, kind, enc_kv=None,
+                 cross_bp=None):
+    """One decoder block, full sequence.  Returns (x, aux).
 
     With ``cfg.seq_shard`` the block boundary is *sequence-parallel*: the
     residual stream (and therefore the activation saved per layer by the
@@ -119,7 +179,7 @@ def _block_train(x, bp, cfg: ModelConfig, window, enc_kv=None, cross_bp=None):
     sd = 1 if cfg.seq_shard else None
     x = constrain(x, model_dim=sd)
     h = rmsnorm(x, bp["ln1"], cfg.norm_eps)
-    attn_out, kv = attention(h, bp, cfg, window=window)
+    attn_out, _ = _attend(h, bp, cfg, window)
     if cfg.block_kind == "hymba":
         ssm_out, _ = ssm_scan(h, bp, cfg)
         attn_out = rmsnorm(attn_out, bp["ln_attn_out"], cfg.norm_eps) + \
@@ -128,44 +188,36 @@ def _block_train(x, bp, cfg: ModelConfig, window, enc_kv=None, cross_bp=None):
     if cross_bp is not None:
         xc = rmsnorm(x, cross_bp["ln"], cfg.norm_eps)
         x = x + cross_attention(xc, cross_bp, cfg, enc_kv)
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.is_moe:
-        h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-        ff, aux = moe_layer(h2, bp, cfg)
-        x = x + ff
-    elif cfg.d_ff:
-        h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-        x = x + mlp(h2, bp, cfg)
-    x = constrain(x, model_dim=sd)
-    return x, aux, kv
+    x, aux = _ffn(x, bp, cfg, kind)
+    return constrain(x, model_dim=sd), aux
 
 
 def _run_decoder_train(params, cfg: ModelConfig, x, windows,
-                       enc_out=None, collect_kv=False):
-    """Scan the decoder stack.  windows: (L,) per-layer window sizes."""
-    blocks = params["blocks"]
+                       enc_out=None):
+    """Scan each decoder stack.  windows: (L,) per-layer window sizes."""
     cross = params.get("cross_blocks")
+    aux = jnp.zeros((), jnp.float32)
+    for _, blocks, kind, sl in _stacks(params, cfg):
+        def body(carry, layer_in, kind=kind):
+            x, aux = carry
+            bp, win, cbp = layer_in
+            enc_kv = None
+            if cross is not None:
+                B, Se, D = enc_out.shape
+                Hkv, hd = cfg.n_kv_heads, cfg.hd
+                ek = (enc_out @ cbp["wk"]).reshape(B, Se, Hkv, hd)
+                ev = (enc_out @ cbp["wv"]).reshape(B, Se, Hkv, hd)
+                enc_kv = (ek, ev)
+            x, a = _block_train(x, bp, cfg, win, kind, enc_kv=enc_kv,
+                                cross_bp=cbp)
+            return (x, aux + a), None
 
-    def body(carry, layer_in):
-        x, aux = carry
-        bp, win, cbp = layer_in
-        enc_kv = None
-        if cross is not None:
-            B, Se, D = enc_out.shape
-            Hkv, hd = cfg.n_kv_heads, cfg.hd
-            ek = (enc_out @ cbp["wk"]).reshape(B, Se, Hkv, hd)
-            ev = (enc_out @ cbp["wv"]).reshape(B, Se, Hkv, hd)
-            enc_kv = (ek, ev)
-        x, a, kv = _block_train(x, bp, cfg, win, enc_kv=enc_kv, cross_bp=cbp)
-        out = kv if collect_kv else None
-        return (x, aux + a), out
-
-    fn = body
-    if cfg.remat == "block":
-        fn = jax.checkpoint(body, prevent_cse=False)
-    xs = (blocks, jnp.asarray(windows), cross)
-    (x, aux), kvs = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)), xs)
-    return x, aux, kvs
+        fn = body
+        if cfg.remat == "block":
+            fn = jax.checkpoint(body, prevent_cse=False)
+        xs = (blocks, jnp.asarray(windows[sl]), cross)
+        (x, aux), _ = jax.lax.scan(fn, (x, aux), xs)
+    return x, aux
 
 
 def _run_encoder(params, cfg: ModelConfig, x):
@@ -218,7 +270,7 @@ def forward(params, cfg: ModelConfig, *, tokens: Optional[jnp.ndarray] = None,
         enc_out = _run_encoder(params, cfg, enc_embeds)
     windows = hymba_windows(cfg, S) if cfg.block_kind == "hymba" else \
         np.full(cfg.n_layers, cfg.attn_window or S, dtype=np.int32)
-    x, aux, _ = _run_decoder_train(params, cfg, x, windows, enc_out=enc_out)
+    x, aux = _run_decoder_train(params, cfg, x, windows, enc_out=enc_out)
     return _head(params, cfg, x), aux
 
 
@@ -234,11 +286,22 @@ def init_cache(cfg: ModelConfig, batch: int, s_max: int, dtype=None,
         return {"pairs": jax.tree.map(
             lambda a: jnp.broadcast_to(a, (L // 2,) + a.shape), st),
             "pos": jnp.zeros((), jnp.int32)}
-    cache = {
-        "k": jnp.zeros((L, batch, s_max, Hkv, hd), dt),
-        "v": jnp.zeros((L, batch, s_max, Hkv, hd), dt),
-        "pos": jnp.zeros((), jnp.int32),
-    }
+    cache = {"pos": jnp.zeros((), jnp.int32)}
+    for prefix, n in (("dense_", cfg.dense_layers),
+                      ("", L - cfg.dense_layers)):
+        if not n:
+            continue
+        a, b = _cache_names(cfg, prefix)
+        if cfg.is_mla:
+            # the latent cache: c_kv and the shared rotary key per token
+            cache[a] = jnp.zeros((n, batch, s_max, cfg.kv_lora_rank), dt)
+            cache[b] = jnp.zeros((n, batch, s_max, cfg.qk_rope_dim), dt)
+        else:
+            cache[a] = jnp.zeros((n, batch, s_max, Hkv, hd), dt)
+            cache[b] = jnp.zeros((n, batch, s_max, Hkv, hd), dt)
+    if cfg.is_mla:
+        sflog.counter("mla.latent_cache_bytes").value = sum(
+            v.size * v.dtype.itemsize for k, v in cache.items() if k != "pos")
     if cfg.block_kind == "hymba":
         cache["h"] = jnp.zeros((L, batch, cfg.ssm_heads, cfg.hd,
                                 cfg.ssm_state), jnp.float32)
@@ -290,15 +353,21 @@ def prefill(params, cfg: ModelConfig, *, tokens=None, embeds=None,
     windows = hymba_windows(cfg, s_max) if cfg.block_kind == "hymba" else \
         np.full(cfg.n_layers, cfg.attn_window or s_max, dtype=np.int32)
 
-    blocks = params["blocks"]
     cross = params.get("cross_blocks")
     hymba = cfg.block_kind == "hymba"
+    valid = lengths = None
+    if last_pos is not None:
+        # a bucketed prompt's pad tail attends to nothing (where a kernel
+        # can skip it) and routes to no held expert
+        lengths = jnp.asarray(last_pos) + 1
+        if cfg.moe_held:
+            valid = jnp.arange(S)[None] < lengths[:, None]
 
-    def body(carry, layer_in):
+    def body(carry, layer_in, kind):
         x = carry
         bp, win, cbp = layer_in
         h = rmsnorm(x, bp["ln1"], cfg.norm_eps)
-        attn_out, kv = attention(h, bp, cfg, window=win)
+        attn_out, kv = _attend(h, bp, cfg, win, kernel=True, lengths=lengths)
         extras = {}
         if hymba:
             ssm_out, hstate = ssm_scan(h, bp, cfg)
@@ -314,30 +383,20 @@ def prefill(params, cfg: ModelConfig, *, tokens=None, embeds=None,
             ev = (enc_out @ cbp["wv"]).reshape(B, Se, Hkv, hd)
             x = x + cross_attention(xc, cbp, cfg, (ek, ev))
             extras["ck"], extras["cv"] = ek, ev
-        if cfg.is_moe:
-            h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-            ff, _ = moe_layer(h2, bp, cfg)
-            x = x + ff
-        elif cfg.d_ff:
-            h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-            x = x + mlp(h2, bp, cfg)
-        k, v = kv
+        x, _ = _ffn(x, bp, cfg, kind, valid)
         # place into fixed-size cache (left-aligned)
-        pad = s_max - k.shape[1]
+        pad = s_max - S
         if pad:
-            k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        out = {"k": k, "v": v, **extras}
-        return x, out
+            kv = tuple(jnp.pad(c, ((0, 0), (0, pad)) + ((0, 0),) * (c.ndim - 2))
+                       for c in kv)
+        return x, (kv, extras)
 
-    xs = (blocks, jnp.asarray(windows), cross)
-    x, outs = jax.lax.scan(body, x, xs)
-    cache = {"k": outs["k"], "v": outs["v"],
-             "pos": jnp.asarray(S, jnp.int32)}
-    if hymba:
-        cache["h"] = outs["h"]
-    if cfg.cross_attention:
-        cache["ck"], cache["cv"] = outs["ck"], outs["cv"]
+    cache = {"pos": jnp.asarray(S, jnp.int32)}
+    for prefix, blocks, kind, sl in _stacks(params, cfg):
+        xs = (blocks, jnp.asarray(windows[sl]), cross)
+        x, (kv, extras) = jax.lax.scan(partial(body, kind=kind), x, xs)
+        cache.update(zip(_cache_names(cfg, prefix), kv))
+        cache.update(extras)
     logits = _head(params, cfg, _last_x(x, last_pos))[:, 0]
     return logits, cache
 
@@ -357,6 +416,11 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray, cache: Dict
         x, states = jax.lax.scan(body, x, (params["pairs"], cache["pairs"]))
         logits = _head(params, cfg, x)[:, 0]
         return logits, {"pairs": states, "pos": pos + 1}
+
+    if cfg.is_mla or cfg.dense_layers:
+        logits, cache = decode_slots(params, cfg, tokens, cache,
+                                     jnp.full((B,), pos, jnp.int32))
+        return logits, {**cache, "pos": pos + 1}
 
     s_max = cache["k"].shape[2]
     windows = hymba_windows(cfg, s_max) if cfg.block_kind == "hymba" else \
@@ -405,3 +469,31 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray, cache: Dict
         new_cache["ck"], new_cache["cv"] = outs["ck"], outs["cv"]
     logits = _head(params, cfg, x)[:, 0]
     return logits, new_cache
+
+
+def decode_slots(params, cfg: ModelConfig, tokens: jnp.ndarray, cache: Dict,
+                 positions: jnp.ndarray) -> Tuple[jnp.ndarray, Dict]:
+    """One decode step in which each batch row has its own position (the
+    serving engine's slots).  tokens, positions: (B,) -> (logits (B, V),
+    cache with each row's new entry written at its position).  Attention
+    is the slot form of the block's kind: GQA (``attention_decode_slots``)
+    or MLA in its absorbed form over the latent cache (``mla_decode``)."""
+    x = jnp.take(params["embed"], tokens[:, None], axis=0)
+    attend = mla_decode if cfg.is_mla else attention_decode_slots
+    cache = dict(cache)
+    for prefix, blocks, kind, _ in _stacks(params, cfg):
+        names = _cache_names(cfg, prefix)
+
+        def body(x, layer_in, kind=kind):
+            bp, c1, c2 = layer_in
+            h = rmsnorm(x, bp["ln1"], cfg.norm_eps)
+            out, c1, c2 = attend(h, bp, cfg, c1, c2, positions)
+            x, _ = _ffn(x + out, bp, cfg, kind)
+            return x, (c1, c2)
+
+        x, new = jax.lax.scan(body, x, (blocks, cache[names[0]],
+                                        cache[names[1]]))
+        cache.update(zip(names, new))
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return (x @ head)[:, 0], cache

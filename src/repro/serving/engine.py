@@ -49,15 +49,16 @@ def next_pow2(n: int) -> int:
 
 @jax.jit
 def serve_cache_insert(cache: Dict, cache1: Dict, slot) -> Dict:
-    """Copy a batch-1 prefill cache into row ``slot`` of the engine cache
-    (every per-slot entry: ``k``/``v``, and hymba's SSM state ``h``)."""
+    """Copy a batch-1 prefill cache into row ``slot`` of the engine cache:
+    every per-slot entry ((L, B, ...): GQA's ``k``/``v``, MLA's latent
+    ``ckv``/``kpe``, hymba's SSM state ``h``, of each layer stack); the
+    scalar ``pos`` stays the engine's."""
     with sflog.scope("serve.cache_insert"):
         out = dict(cache)
-        for name in ("k", "v", "h"):
-            if name in cache:
+        for name, c in cache.items():
+            if c.ndim >= 2 and name in cache1:
                 out[name] = jax.lax.dynamic_update_index_in_dim(
-                    cache[name], cache1[name][:, 0].astype(cache[name].dtype),
-                    slot, axis=1)
+                    c, cache1[name][:, 0].astype(c.dtype), slot, axis=1)
         return out
 
 
@@ -193,69 +194,9 @@ class ServeEngine:
 
     @staticmethod
     def _decode_impl(cfg, params, tokens, cache, positions):
-        """Per-slot-position decode: like T.decode_step but each batch row
-        has its own position."""
-        x = jnp.take(params["embed"], tokens[:, None], axis=0)
-        from ..models.layers import rmsnorm, rope
-        B = x.shape[0]
-        blocks = params["blocks"]
-        pos = positions
-
-        def body(x, layer_in):
-            bp, ck, cv = layer_in
-            h = rmsnorm(x, bp["ln1"], cfg.norm_eps)
-            with sflog.scope("model.attn"):
-                H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-                q = (h @ bp["wq"]).reshape(B, 1, H, hd)
-                k = (h @ bp["wk"]).reshape(B, 1, Hkv, hd)
-                v = (h @ bp["wv"]).reshape(B, 1, Hkv, hd)
-                if cfg.qk_norm:
-                    q = rmsnorm(q, bp["q_norm"], cfg.norm_eps)
-                    k = rmsnorm(k, bp["k_norm"], cfg.norm_eps)
-                # per-row rope + cache write
-                def rope1(u, p_):
-                    # u: (H, hd), p_: scalar -> rope at one absolute position
-                    return rope(u[None], p_[None], cfg.rope_theta)[0]
-                q = jax.vmap(rope1)(q[:, 0], pos)[:, None]  # (B, 1, H, hd)
-                k = jax.vmap(rope1)(k[:, 0], pos)[:, None]
-                ck = jax.vmap(
-                    lambda c, kk, p_: jax.lax.dynamic_update_slice(
-                        c, kk.astype(c.dtype), (p_, 0, 0)))(
-                            ck, k[:, 0][:, None], pos)
-                cv = jax.vmap(
-                    lambda c, vv, p_: jax.lax.dynamic_update_slice(
-                        c, vv.astype(c.dtype), (p_, 0, 0)))(
-                            cv, v[:, 0][:, None], pos)
-                rep = H // Hkv
-                scale = 1.0 / np.sqrt(hd)
-                kf = jnp.repeat(ck.astype(jnp.float32), rep, axis=2)
-                vf = jnp.repeat(cv.astype(jnp.float32), rep, axis=2)
-                s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                               kf) * scale
-                kpos = jnp.arange(ck.shape[1])
-                mask = kpos[None] <= pos[:, None]
-                if cfg.attn_window:
-                    mask &= kpos[None] > pos[:, None] - cfg.attn_window
-                s = jnp.where(mask[:, None, None, :], s, -1e30)
-                pr = jax.nn.softmax(s, axis=-1)
-                attn = jnp.einsum("bhqk,bkhd->bqhd", pr, vf).astype(x.dtype)
-                x = x + attn.reshape(B, 1, H * hd) @ bp["wo"]
-            h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-            if cfg.is_moe:
-                from ..models.moe import moe_layer
-                ff, _ = moe_layer(h2, bp, cfg)
-                x = x + ff
-            elif cfg.d_ff:
-                from ..models.layers import mlp
-                x = x + mlp(h2, bp, cfg)
-            return x, {"k": ck, "v": cv}
-
-        x, outs = jax.lax.scan(body, x, (blocks, cache["k"], cache["v"]))
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = (x @ head)[:, 0]
-        cache = {**cache, "k": outs["k"], "v": outs["v"]}
-        return logits, cache
+        """Per-slot-position decode (each batch row at its own position):
+        the model's slot decode, GQA or MLA by the block's kind."""
+        return T.decode_slots(params, cfg, tokens, cache, positions)
 
     # ------------------------------------------------------------- plumbing
     def submit(self, req: Request):

@@ -122,3 +122,26 @@ def test_flash_matches_chunked_training_path(rng):
         qq, kk, vv, causal=True, block_q=32, block_k=32))(q, k, v)
     np.testing.assert_allclose(np.asarray(kernel), np.asarray(chunked),
                                rtol=2e-4, atol=2e-5)
+
+
+def test_flash_heads_real_lengths_and_value_dim(rng):
+    """Head-major kernel with values of another head dim (latent
+    attention: 24 against 16 here) and a real length per head row: the
+    real rows equal attention over the real prefix alone, and query blocks
+    wholly past it come out zero (skipped)."""
+    from repro.kernels.flash_attention import flash_attention_heads
+    H, S, Dq, Dv, blk = 3, 80, 24, 16, 16
+    q = jnp.asarray(rng.standard_normal((H, S, Dq)).astype(np.float32) * .3)
+    k = jnp.asarray(rng.standard_normal((H, S, Dq)).astype(np.float32) * .3)
+    v = jnp.asarray(rng.standard_normal((H, S, Dv)).astype(np.float32))
+    lengths = np.array([80, 37, 5], np.int32)
+    got = np.asarray(flash_attention_heads(q, k, v, jnp.asarray(lengths),
+                                           scale=0.2, block_q=blk,
+                                           block_k=blk))
+    for h, n in enumerate(lengths):
+        want = R.flash_attention_ref(q[h, :n, None], k[h, :n, None],
+                                     v[h, :n, None], scale=0.2)[:, 0]
+        np.testing.assert_allclose(got[h, :n], np.asarray(want),
+                                   rtol=2e-4, atol=2e-5)
+        past = -(-n // blk) * blk
+        assert not np.any(got[h, past:])

@@ -131,3 +131,18 @@ def test_pack_strided_compiles(compile_tpu):
         lambda d: pack_strided(d, start=1 + n + n * n, dims=dims,
                                strides=strides, interpret=False),
         ((n ** 3, 4), jnp.float32)))
+
+
+@pytest.mark.parametrize("H,S,Dq,Dv", [(64, 16384, 192, 128),
+                                       (32, 4096, 128, 128)],
+                         ids=["kimi-mla-prefill", "gqa"])
+def test_flash_attention_compiles(compile_tpu, H, S, Dq, Dv):
+    """The causal flash kernel at the latent-attention prefill's widths
+    (Kimi-K2: 64 heads, q/k 192 = nope 128 + rope 64, v 128, a 16k bucket)
+    and at a plain head dim."""
+    from repro.kernels.flash_attention import flash_attention_heads
+    _assert_kernel(compile_tpu(
+        lambda q, k, v: flash_attention_heads(q, k, v, scale=0.13,
+                                              interpret=False),
+        ((H, S, Dq), jnp.bfloat16), ((H, S, Dq), jnp.bfloat16),
+        ((H, S, Dv), jnp.bfloat16)))
