@@ -2,8 +2,9 @@
 
 The SF hot-path entry points (``pack_rows``, ``segment_reduce_rows``,
 ``local_bcast_rows``) are *autotuned*: each has several candidate lowerings
-(pure-XLA gather/segment ops, row-blocked DMA kernels at several block
-sizes, the fused local-exchange kernel) and :mod:`repro.kernels.tuning`
+(pure-XLA gather/segment ops, position-wise combines with one row gather
+for short segments, row-blocked DMA kernels at several block sizes, the
+fused local-exchange kernel) and :mod:`repro.kernels.tuning`
 sweeps them once per problem signature, memoizing the winner so repeated
 exchanges never re-sweep or re-trace.  A kernel is a candidate only where
 the shape rules of :mod:`repro.kernels.sf_pack` /
@@ -30,7 +31,7 @@ from .sf_pack import (bcast_fused as _bcast_fused, kernel_dtype_ok,
                       pack_blocked as _pack_blocked,
                       pack_strided as _pack_strided, strided_ok, sublane_rows)
 from .sf_unpack import (seg_block_ok, segment_reduce_blocked,
-                        unpack_segments)
+                        segment_reduce_gather, unpack_segments)
 from .spmv_ell import spmv_ell as _spmv_ell
 from .tuning import resolve_interpret
 
@@ -60,11 +61,15 @@ _DISPATCH: dict = {}
 tuning.register_cache(_DISPATCH)
 
 
-def _scope(name: str):
-    """``sflog.scope``; imported at trace time, since ``repro.core`` imports
-    this module while it initialises."""
+def _sflog():
+    """:mod:`repro.core.sflog`, imported at trace time, since
+    ``repro.core`` imports this module while it initialises."""
     from ..core import sflog
-    return sflog.scope(name)
+    return sflog
+
+
+def _scope(name: str):
+    return _sflog().scope(name)
 
 
 # --------------------------------------------------------------------------
@@ -163,12 +168,26 @@ def _pack_dispatch(sig, dshape, idx_shape, dts, interpret):
 # --------------------------------------------------------------------------
 # segment reduce: tuned sorted-buffer reduction
 # --------------------------------------------------------------------------
+# Longest segment for which the sweep also times the ``gather`` lowering.
+# On a TPU v5e, for 2.1M segments of 4-f32 rows, the kernels' one window
+# DMA per segment costs ~120 ms whatever ``Lmax``; the gather lowering
+# costs 9.3 ms at ``Lmax`` 1, 9.6 ms at 4 and 10.1 ms at 8 (13.7 ms at 32),
+# and 9.6 ms on the DMDA star-stencil plan (segments of 1-4 rows) (PERF.md
+# §6 has the chip measurements).  8 covers the star (4) and box (8)
+# stencils; longer segments, such as an allreduce over more ranks, keep
+# the kernels until measured on their own shapes.
+SEG_GATHER_MAX_LEN = 8
+
+
 def _seg_candidates(S: int, Lmax: int, op: str, unit, dtype,
                     interpret: bool, have_ids: bool) -> dict:
     impls = {}
     if have_ids:
         impls["xla"] = lambda v, f, l, ids: ref.unpack_segment_ref(
             v, ids, num_segments=S, op=op)
+    if have_ids and Lmax <= SEG_GATHER_MAX_LEN:
+        impls["gather"] = lambda v, f, l, ids: segment_reduce_gather(
+            v, f, l, ids, Lmax=Lmax, op=op)
     if not kernel_dtype_ok(dtype):
         return impls
     for SB in _blocks(S, (8, 32, 128), sublane_rows(dtype), 1024):
@@ -210,7 +229,8 @@ def segment_reduce_rows(sorted_vals, seg_first, seg_len, *, num_segments,
 
 def _segred_dispatch(sig, vshape, dts, S, Lmax, op, have_ids, interpret):
     """Build (once per signature) the jitted dispatcher around the winning
-    segment-reduce lowering."""
+    segment-reduce lowering; each built program counts
+    ``sf.combine.<lowering>`` once."""
     scalar_rows = len(vshape) == 1
     kunit = vshape[1:] if not scalar_rows else (1,)
     M = int(vshape[0])
@@ -239,6 +259,7 @@ def _segred_dispatch(sig, vshape, dts, S, Lmax, op, have_ids, interpret):
     impl = impls[winner]
 
     def sf_segment_reduce(v, f, l, ids):
+        _sflog().counter(f"sf.combine.{winner}").add()
         with _scope("sf.combine"):
             out = impl(v[:, None] if scalar_rows else v, f, l, ids)
             return out[:, 0] if scalar_rows else out

@@ -19,7 +19,10 @@ with per-segment (start, length) metadata in SMEM.  The sorted buffer stays
 in HBM in the lane-dense row view of :func:`repro.kernels.sf_pack.row_view`;
 each segment's rows are DMA'd as the aligned window of whole ``t``-row tiles
 that covers them (``Lmax`` bounds the window), masked to the segment, and
-reduced in a 32-bit accumulator.
+reduced in a 32-bit accumulator.  For short segments
+:func:`segment_reduce_gather` computes the same reduction in XLA, with no
+kernel and no lane padding: ``Lmax`` position-wise shifted combines of
+the whole buffer, then one row gather at the segment heads.
 
 Supported ops: sum, max, min, prod (replace is handled by the caller via the
 precomputed last-writer trick and never reaches this kernel).
@@ -40,9 +43,12 @@ from .sf_pack import (VMEM_BUDGET, _acc_dtype, _chunked, _lane_width,
 from .tuning import resolve_interpret
 
 __all__ = ["unpack_segments", "segment_reduce_sorted",
-           "segment_reduce_blocked", "seg_window_rows", "seg_block_ok"]
+           "segment_reduce_blocked", "segment_reduce_gather",
+           "seg_window_rows", "seg_block_ok"]
 
 _REDUCE = {"sum": jnp.sum, "prod": jnp.prod, "max": jnp.max, "min": jnp.min}
+_COMBINE = {"sum": jnp.add, "prod": jnp.multiply, "max": jnp.maximum,
+            "min": jnp.minimum}
 
 
 def _identity(op: str, acc):
@@ -50,8 +56,10 @@ def _identity(op: str, acc):
         return 0
     if op == "prod":
         return 1
-    big = jnp.inf if acc == jnp.float32 else jnp.iinfo(jnp.int32).max
-    return -big if op == "max" else big
+    if jnp.issubdtype(acc, jnp.floating):
+        return -jnp.inf if op == "max" else jnp.inf
+    info = jnp.iinfo(acc)
+    return info.min if op == "max" else info.max
 
 
 def seg_window_rows(Lmax: int, dtype) -> int:
@@ -157,6 +165,42 @@ def segment_reduce_blocked(buf: jnp.ndarray, seg_start: jnp.ndarray,
 
     out = _chunked(call, meta, SB)
     return out[:S, :U].reshape((S,) + unit)
+
+
+@functools.partial(jax.jit, static_argnames=("Lmax", "op"))
+def segment_reduce_gather(buf: jnp.ndarray, seg_start: jnp.ndarray,
+                          seg_len: jnp.ndarray, seg_of_slot: jnp.ndarray, *,
+                          Lmax: int, op: str = "sum") -> jnp.ndarray:
+    """Reduce sorted rows into per-segment rows with one row gather.
+
+    Every sorted row ``j`` is combined with the rows ``j + k``,
+    ``k = 1, ..., Lmax - 1``, in that order (the sorted order, so the
+    result is deterministic), for as long as they lie in ``j``'s segment
+    (``seg_of_slot``); these are whole-buffer shifted slices, with no
+    gather.  A segment's first row then holds the segment's reduction, and
+    one gather at ``seg_start`` reads them out; a zero-length segment
+    emits the op's identity.  The rows are read in the layout they have,
+    and accumulate as in the kernels (``_acc_dtype``; rows of 32 bits or
+    more in their own type).  Same result as :func:`segment_reduce_blocked`
+    for segments of at most ``Lmax`` rows."""
+    S = int(seg_start.shape[0])
+    M, unit = int(buf.shape[0]), buf.shape[1:]
+    acc_dt = buf.dtype if buf.dtype.itemsize >= 4 else _acc_dtype(buf.dtype)
+    ident = _identity(op, acc_dt)
+    if M == 0:
+        return jnp.full((S,) + unit, ident, buf.dtype)
+    lanes = (1,) * len(unit)
+    ids = seg_of_slot.astype(jnp.int32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (M,), 0)
+    acc = buf.astype(acc_dt)
+    for k in range(1, min(int(Lmax), M)):
+        same = (jnp.roll(ids, -k) == ids) & (row < M - k)
+        nxt = jnp.roll(buf, -k, axis=0).astype(acc_dt)
+        acc = jnp.where(same.reshape((M,) + lanes),
+                        _COMBINE[op](acc, nxt), acc)
+    out = jnp.take(acc, seg_start.astype(jnp.int32), axis=0, mode="clip")
+    out = jnp.where((seg_len > 0).reshape((S,) + lanes), out, ident)
+    return out.astype(buf.dtype)
 
 
 def segment_reduce_sorted(buf: jnp.ndarray, seg_start: jnp.ndarray,

@@ -211,3 +211,36 @@ def test_halo_unpack_bitwise_equals_numpy_scatter(interior, backend, rng):
         ufunc.at(want, gid[pos], lv[pos])
         got = np.asarray(da.local_to_global(lv, op=op, backend=backend))
         assert got.tobytes() == want.tobytes(), op
+
+
+@pytest.mark.parametrize("stencil,Lmax", [("star", 4), ("box", 8)])
+def test_local_to_global_gather_combine(stencil, Lmax, rng, monkeypatch):
+    """DMLocalToGlobal (sum) on the pallas backend with the segment reduce
+    pinned to the ``gather`` lowering equals the swept lowering and a numpy
+    scatter bit for bit (small integers: exact in any order); the built
+    combine program counts ``sf.combine.gather`` once."""
+    from repro.core import sflog
+    from repro.kernels import tuning
+    da = DMDA((8, 8, 8), 8, stencil=stencil, width=1, periodic=False)
+    gid = _local_gid(da)
+    pos = np.flatnonzero(gid >= 0)
+    assert np.bincount(gid[pos]).max() == Lmax     # longest segment
+    lv = rng.integers(-8, 8, (da.nlocal_total, 4)).astype(np.float32)
+    want = np.zeros((da.nglobal, 4), np.float32)
+    np.add.at(want, gid[pos], lv[pos])
+
+    def l2g():
+        tuning.clear_cache()
+        out = np.asarray(DMDA((8, 8, 8), 8, stencil=stencil, width=1,
+                              periodic=False).local_to_global(
+                                  lv, op="sum", backend="pallas"))
+        tuning.clear_cache()
+        return out
+
+    swept = l2g()
+    monkeypatch.setenv("REPRO_SF_IMPL_SEGRED", "gather")
+    before = sflog.counters().get("sf.combine.gather", 0)
+    pinned = l2g()
+    assert sflog.counters()["sf.combine.gather"] - before == 1
+    assert pinned.tobytes() == want.tobytes()
+    assert swept.tobytes() == want.tobytes()

@@ -19,6 +19,7 @@ from repro.kernels import ops as K
 from repro.kernels.sf_pack import (bcast_fused, pack, pack_block_ok,
                                    pack_blocked, pack_strided)
 from repro.kernels.sf_unpack import (seg_block_ok, segment_reduce_blocked,
+                                     segment_reduce_gather,
                                      segment_reduce_sorted)
 
 # the MoE hidden rows of phi3.5-moe (d_model 4096, bf16) and the halo rows
@@ -89,9 +90,10 @@ def test_pack_blocks_compile(compile_tpu, case):
 
 @pytest.mark.parametrize("op", ["sum", "max"])
 def test_segment_reduce_compiles(compile_tpu, op):
-    """``segment_reduce_sorted`` and every kept ``segment_reduce_blocked``
-    block on a DMDA local_to_global buffer (4-dof f32 rows, Lmax 4; one
-    SMEM chunk of segments)."""
+    """``segment_reduce_sorted``, every kept ``segment_reduce_blocked``
+    block and the ``gather`` lowering (XLA, one row gather, no kernel) on a
+    DMDA local_to_global buffer (4-dof f32 rows, Lmax 4; one SMEM chunk of
+    segments)."""
     M, S, L, U = 2 ** 17, 2 ** 15, 4, (4,)
     shapes = (((M,) + U, jnp.float32), ((S,), jnp.int32), ((S,), jnp.int32))
     _assert_kernel(compile_tpu(
@@ -109,6 +111,11 @@ def test_segment_reduce_compiles(compile_tpu, op):
                 b, f, l, num_segments=S, Lmax=L, segs_per_block=SB, op=op,
                 interpret=False),
             *shapes))
+    assert "gather" in K._seg_candidates(S, L, op, U, jnp.float32, False,
+                                         True)
+    hlo = compile_tpu(lambda b, f, l, i: segment_reduce_gather(
+        b, f, l, i, Lmax=L, op=op), *shapes, ((M,), jnp.int32))
+    assert hlo.count(" gather(") == 1
 
 
 @pytest.mark.parametrize("case", [HALO_4DOF, MOE], ids=["4dof", "moe"])

@@ -1,16 +1,18 @@
 """Kernel autotuning / interpret-policy tests (repro.kernels.tuning) and
-conformance sweeps for the blocked Pallas lowerings the autotuner picks
-between (sf_pack.pack_blocked, sf_unpack.segment_reduce_blocked,
-sf_pack.bcast_fused)."""
+conformance sweeps for the lowerings the autotuner picks between
+(sf_pack.pack_blocked, sf_unpack.segment_reduce_blocked,
+sf_unpack.segment_reduce_gather, sf_pack.bcast_fused)."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.mpiops import get_op
 from repro.kernels import ops as K
-from repro.kernels import tuning
+from repro.kernels import ref, tuning
 from repro.kernels.sf_pack import bcast_fused, pack_blocked
-from repro.kernels.sf_unpack import segment_reduce_blocked
+from repro.kernels.sf_unpack import (segment_reduce_blocked,
+                                     segment_reduce_gather)
 
 
 @pytest.fixture(autouse=True)
@@ -223,6 +225,59 @@ def test_segment_reduce_blocked_conformance(op, SB, rng):
         for j in range(int(lens[s])):
             want[s] = ufunc(want[s], vals[int(first[s]) + j])
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+
+
+_UFUNC = {"sum": np.add, "max": np.maximum, "min": np.minimum,
+          "prod": np.multiply}
+
+
+@pytest.mark.parametrize("op", ["sum", "max", "min", "prod"])
+@pytest.mark.parametrize("unit", [(), (4,), (3, 2)])
+@pytest.mark.parametrize("dt", [np.float32, np.int32, jnp.bfloat16])
+@pytest.mark.parametrize("L", [1, 4, 8])
+def test_segment_reduce_gather_conformance(op, unit, dt, L, rng):
+    """The ``gather`` lowering equals a numpy loop that combines each
+    segment's rows in sorted order in 32 bits, and the XLA segment op, on
+    ragged segments with zero-length ones (the op's identity) and segments
+    of ``L`` rows at the buffer's end."""
+    lens = np.minimum(np.array([3, 0, 5, L, 1, 2, 0, 7, 4, L]), L)
+    S = lens.size
+    first = np.concatenate([[0], np.cumsum(lens)[:-1]])
+    M = int(lens.sum())
+    vals = rng.integers(1, 4, (M,) + unit) if dt is np.int32 else \
+        rng.standard_normal((M,) + unit) + 1.5
+    vals = np.asarray(jnp.asarray(vals.astype(np.float32)).astype(dt))
+    acc = np.int32 if dt is np.int32 else np.float32
+    want = np.full((S,) + unit, get_op(op).identity_of(acc), acc)
+    for s in range(S):
+        for j in range(int(lens[s])):
+            want[s] = _UFUNC[op](want[s], vals[first[s] + j].astype(acc))
+    want = np.asarray(jnp.asarray(want).astype(dt))
+    ids = np.repeat(np.arange(S), lens)
+    got = np.asarray(segment_reduce_gather(jnp.asarray(vals), first, lens,
+                                           ids, Lmax=L, op=op))
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
+    xla = ref.unpack_segment_ref(jnp.asarray(vals), jnp.asarray(ids),
+                                 num_segments=S, op=op)
+    tol = 1e-2 if dt is jnp.bfloat16 else 1e-6
+    np.testing.assert_allclose(got.astype(np.float32),
+                               np.asarray(xla).astype(np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("L,offered", [(1, True), (K.SEG_GATHER_MAX_LEN, True),
+                                       (K.SEG_GATHER_MAX_LEN + 1, False)])
+def test_segment_reduce_gather_offered_up_to_bound(L, offered):
+    """The sweep times the ``gather`` lowering for segments of at most
+    ``SEG_GATHER_MAX_LEN`` rows, for kernel and non-kernel dtypes alike,
+    where the caller gives each sorted row's segment, and never past the
+    bound."""
+    for dt in (jnp.float32, jnp.bfloat16, jnp.float16):
+        impls = K._seg_candidates(64, L, "sum", (4,), dt, True, True)
+        assert ("gather" in impls) is offered
+        impls = K._seg_candidates(64, L, "sum", (4,), dt, True, False)
+        assert "gather" not in impls
 
 
 def test_bcast_fused_conformance(rng):
